@@ -34,6 +34,8 @@ def main() -> None:
     import types
 
     from repro import obs
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     rec = obs.configure(metrics_dir=args.metrics_dir or None,
                         trace_path=args.trace or None,
                         process_name="repro.bench")

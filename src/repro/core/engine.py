@@ -24,9 +24,12 @@ Schedules decide *which sites* a call updates:
 Backends decide *where* the sweep runs:
   * ``"jnp"``    — fused pure-jnp schedules tuned for CPU/GPU;
   * ``"pallas"`` — the fused Pallas TPU kernel (interpret mode off-TPU);
+    raises :class:`~repro.kernels.ops.KernelTooLarge` when the kernel's
+    VMEM working set for the graph exceeds the chip's;
   * ``"dist"``   — shard_map over a (data, model) mesh (graph column-
     sharded, one psum per sweep; ``runtime/dist_gibbs.py``), pass ``mesh=``;
-  * ``"auto"``   — pallas on TPU, jnp elsewhere.
+  * ``"auto"``   — pallas on TPU where the kernel fits VMEM, jnp elsewhere
+    (``Engine.describe()`` names the backend it picked).
 
 The registry (`register` / `make` / `names`) subsumes the previous three
 divergent construction paths (``make_*_step``, ``make_*_sweep``,
@@ -46,6 +49,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from .factor_graph import (MatchGraph, make_ising_graph, make_potts_graph,
                            make_lattice_ising, lattice_colors,
@@ -53,6 +57,7 @@ from .factor_graph import (MatchGraph, make_ising_graph, make_potts_graph,
 from .estimators import (recommended_capacity, draw_global_minibatch,
                          min_gibbs_estimate)
 from . import samplers as S
+from ..kernels.ops import KernelTooLarge
 
 __all__ = [
     "Engine", "Schedule", "UniformSites", "ChromaticBlocks", "AdaptiveScan",
@@ -202,6 +207,10 @@ class Engine:
     # pre-clamp configuration and is stale after evidence overwrites x.
     refresh_cache_fn: Optional[Callable] = dataclasses.field(
         default=None, repr=False)
+    # the dist backend's graph tables as placed on its mesh (name -> array
+    # sharded over the model axis); None on the single-device backends
+    device_tables: Optional[Dict[str, jax.Array]] = dataclasses.field(
+        default=None, repr=False)
 
     def init(self, key: jax.Array, n_chains: int, **kwargs):
         """Batched initial state for ``n_chains`` chains (cached-estimator
@@ -297,8 +306,12 @@ class Engine:
         return st if inner is None else state._replace(inner=st)
 
     def describe(self) -> Dict[str, Any]:
-        """Machine-readable identity (benchmarks attach this to records)."""
+        """Machine-readable identity (benchmarks attach this to records).
+        ``interpret`` is True when the pallas backend runs its kernels in
+        interpret mode (off-TPU) instead of compiled with Mosaic."""
+        from ..kernels.ops import interpret
         return {"engine": self.name, "backend": self.backend,
+                "interpret": self.backend == "pallas" and interpret(),
                 "schedule": self.schedule.describe(),
                 "updates_per_call": self.updates_per_call}
 
@@ -357,8 +370,13 @@ def make(name: str, graph: MatchGraph, *, sweep: Optional[int] = None,
     if not isinstance(schedule, Schedule):
         raise TypeError(f"schedule must be a Schedule, got {schedule!r}")
     if backend == "auto":
-        backend = "pallas" if (jax.default_backend() == "tpu"
-                               and "pallas" in supported) else "jnp"
+        if jax.default_backend() == "tpu" and "pallas" in supported:
+            try:
+                return builder(graph, schedule=schedule, backend="pallas",
+                               mesh=mesh, **params)
+            except KernelTooLarge:
+                pass                  # the graph outgrows the kernel's VMEM
+        backend = "jnp"
     if backend not in supported:
         raise ValueError(f"engine {name!r} supports backends {supported}, "
                          f"got {backend!r}")
@@ -583,7 +601,7 @@ def _dist_engine(name: str, graph: MatchGraph, schedule: Schedule, mesh,
     schedules, one per color class on the chromatic schedule — with
     donated state."""
     from ..runtime import dist_gibbs as DG
-    from ..launch.mesh import compat_shard_map, dp_axes, MP_AXIS
+    from ..launch.mesh import dp_axes, MP_AXIS
 
     mp = mesh.shape[MP_AXIS]
     dps = dp_axes(mesh)                       # ("data",) or ("pod", "data")
@@ -674,16 +692,27 @@ def _dist_engine(name: str, graph: MatchGraph, schedule: Schedule, mesh,
         st_specs = DG.state_specs(dp_axes=dps)
 
     sh_specs = DG.shard_specs()
-    smapped = compat_shard_map(lambda st, sh: step(st, sh), mesh,
-                               (st_specs, sh_specs), st_specs)
-    sh = {k: getattr(gs, k) for k in sh_specs}
+    smapped = jax.shard_map(lambda st, sh: step(st, sh), mesh=mesh,
+                            in_specs=(st_specs, sh_specs),
+                            out_specs=st_specs, check_vma=False)
+    # the graph tables are placed on the mesh once: each model shard's
+    # device holds its column slab, and no call re-lays them out
+    sh = {k: jax.device_put(getattr(gs, k), NamedSharding(mesh, spec))
+          for k, spec in sh_specs.items()}
     # state donation: avoids double-buffering the (C, n, D) marginal sums
     # at scale; Engine.sweep documents the rebind-don't-reuse contract
     jstep = jax.jit(smapped, donate_argnums=(0,))
 
+    # XLA:CPU's in-process collectives deadlock when several of these
+    # sharded programs are in flight at once (a device's thread waits in
+    # one program's all-reduce while a peer is still in another's), so a
+    # CPU mesh runs one sweep at a time; a TPU mesh dispatches ahead
+    in_order = mesh.devices.flat[0].platform == "cpu"
+
     def sweep_fn(state):
         with mesh:
-            return jstep(state, sh)
+            out = jstep(state, sh)
+        return jax.block_until_ready(out) if in_order else out
 
     def init_fn(key: jax.Array, n_chains: int, *, start: str = "constant"):
         if start != "constant":
@@ -709,9 +738,10 @@ def _dist_engine(name: str, graph: MatchGraph, schedule: Schedule, mesh,
                 calls=jnp.int32(0))
         return st
 
-    return _engine(name, "dist", schedule, upd, graph, resolved,
-                   init_fn, sweep_fn,
-                   exact_accept=name in ("gibbs", "min-gibbs"))
+    eng = _engine(name, "dist", schedule, upd, graph, resolved,
+                  init_fn, sweep_fn,
+                  exact_accept=name in ("gibbs", "min-gibbs"))
+    return dataclasses.replace(eng, device_tables=sh)
 
 
 # ---------------------------------------------------------------------------

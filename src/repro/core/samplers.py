@@ -414,6 +414,8 @@ def _build_gibbs_sweep(graph: MatchGraph, sweep_len: int, *,
     """
     _check_impl(impl)
     n, D = graph.n, graph.D
+    if impl == "pallas":
+        kernel_ops.check_sweep_vmem("gibbs", n=n, D=D, S=sweep_len)
 
     def sweep(state: ChainState, sites=None, evidence=None):
         ki, kg, knew = _batch_keys(state.key, 3)
@@ -460,6 +462,7 @@ def _build_mgpmh_sweep(graph: MatchGraph, lam: float, capacity: int,
                                      collect_stats=collect_stats)
     n, D = graph.n, graph.D
     scale = float(graph.L / lam)
+    kernel_ops.check_sweep_vmem("mgpmh", n=n, D=D, S=sweep_len, K=capacity)
 
     def sweep(state: ChainState, sites=None, evidence=None):
         ki, kb, k1, k2, kg, ka, knew = _batch_keys(state.key, 7)
@@ -671,6 +674,7 @@ def _build_min_gibbs_sweep_pallas(graph: MatchGraph, lam: float,
     ``min_gibbs_sweep_pallas_rng`` bench variant generates the same streams
     in-kernel so they never exist in HBM."""
     n, D, S, K = graph.n, graph.D, sweep_len, capacity
+    kernel_ops.check_sweep_vmem("min-gibbs", n=n, D=D, S=S, K=K)
     lscale = float(np.log1p(graph.psi / lam))
     node_prob, node_alias = _node_alias_table(graph)
 
@@ -806,6 +810,7 @@ def _build_double_min_sweep_pallas(graph: MatchGraph, lam1: float,
     interpret mode)."""
     n, D, S = graph.n, graph.D, sweep_len
     K1, K2 = capacity1, capacity2
+    kernel_ops.check_sweep_vmem("doublemin", n=n, D=D, S=S, K=K1, K2=K2)
     scale1 = float(graph.L / lam1)
     lscale2 = float(np.log1p(graph.psi / lam2))
     node_prob, node_alias = _node_alias_table(graph)
@@ -899,6 +904,9 @@ def _build_chromatic_gibbs_sweep(graph: MatchGraph, colors, *,
     classes = [jnp.asarray(s, jnp.int32)
                for s in validate_coloring(graph, colors)]
     n_colors = len(classes)
+    if impl == "pallas":
+        kernel_ops.check_sweep_vmem("gibbs", n=n, D=D,
+                                    S=max(int(c.shape[0]) for c in classes))
 
     def sweep(state: ChainState, evidence=None):
         C = state.x.shape[0]

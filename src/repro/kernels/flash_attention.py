@@ -19,11 +19,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:                      # VMEM scratch works in interpret mode too
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = pltpu.VMEM
-except Exception:         # pragma: no cover
-    _SCRATCH = None
+from jax.experimental.pallas import tpu as pltpu
+
+_SCRATCH = pltpu.VMEM     # VMEM scratch works in interpret mode too
 
 __all__ = ["flash_attention_pallas"]
 

@@ -16,7 +16,8 @@ primitives —
     *two-stage* chain: endpoint ``a`` from a node table with p_a = L_a/2Psi,
     endpoint ``b`` from row a's table with p_b = W_ab/L_a, so
     p({a,b}) = M_phi/Psi exactly without the O(n^2) flat factor table).
-    All gathers are realized as one-hot GEMMs so the MXU does the indexing;
+    All gathers are realized as one-hot GEMMs so the MXU does the indexing,
+    at full f32 precision so they stay exact;
   * **bucket-energy reductions** — ``_bucket``: weighted one-hot
     contractions (the MXU trick of kernels/minibatch_energy.py);
   * **Gumbel-argmax proposal** — ``_argmax_lanes`` over masked lanes
@@ -50,11 +51,15 @@ Tiling / VMEM budget (per grid step, grid = (C/BC,)):
   resident:  the (Np, Np) tables each algorithm needs (W and/or
              row_prob/row_alias; MIN-Gibbs and DoubleMIN skip W entirely),
              x (BC x Np), the per-sub-step uniform/weight blocks
-             (host-rng path only);
+             (host-rng path only), each double-buffered;
   transient: one-hot blocks (BC, L, Np) where L is the draw-lane width
-             (Kp for mgpmh/doublemin, D*Kp for MIN-Gibbs's D independent
-             candidate minibatches).
+             (Kp for mgpmh/doublemin; MIN-Gibbs loops over its D candidate
+             minibatches, Kp lanes each).
   Np/Kp/Dp are 128-multiples (lane width), BC a multiple of 8 (sublanes).
+  Every kernel compiles under ``VMEM_LIMIT_BYTES``; ``ops.sweep_vmem_bytes``
+  bounds the total from above and the engine refuses a graph past it.
+  Per-sub-step scalars are read from their (BC, Sp) blocks by a masked
+  lane reduction: Mosaic refuses dynamic unaligned lane slices.
 """
 from __future__ import annotations
 
@@ -64,10 +69,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu namespace may be unavailable on CPU-only jaxlib builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["mgpmh_sweep_pallas", "mgpmh_sweep_pallas_rng",
            "gibbs_sweep_pallas",
@@ -75,6 +77,12 @@ __all__ = ["mgpmh_sweep_pallas", "mgpmh_sweep_pallas_rng",
            "double_min_sweep_pallas", "double_min_sweep_pallas_rng"]
 
 _NEG = -1e30
+
+# Scoped-VMEM limit every fused kernel compiles under (v5e has 128 MiB of
+# VMEM; Mosaic's default scoped limit is 16 MiB).  ``ops.sweep_vmem_bytes``
+# must stay an upper bound of what the compiler allocates under it.
+VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 # ---------------------------------------------------------------------------
@@ -86,9 +94,26 @@ def _uniform_from_bits(bits):  # pragma: no cover - TPU-compiled path
     return (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
 
 
+def _lane_at(block, s):
+    """``block[:, s]`` of a (BC, Sp) block as (BC, 1), by a masked lane
+    reduction: Mosaic refuses a dynamic lane slice it cannot prove is a
+    multiple of 128, which ``block[:, pl.ds(s, 1)]`` is not."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    return jnp.sum(jnp.where(iota == s, block, jnp.zeros_like(block)),
+                   axis=1, keepdims=True)
+
+
+# The one-hot GEMMs below are gathers and exact sums, so they contract at
+# full f32 precision: at Mosaic's default precision the weights, row
+# probabilities and alias indices (> 256 on potts-20x20) lose bits, and on
+# a v5e chip only 11-98% of chains matched the jnp oracle (CHANGES.md).
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 def _row_select(oh_i, table):
     """Gather rows table[i] for per-chain site ids via one-hot GEMM."""
-    return jax.lax.dot(oh_i, table, preferred_element_type=jnp.float32)
+    return jax.lax.dot(oh_i, table, precision=_EXACT,
+                       preferred_element_type=jnp.float32)
 
 
 def _bucket(w, onehot):
@@ -96,7 +121,7 @@ def _bucket(w, onehot):
     acc = jax.lax.dot_general(
         w[:, None, :], onehot,
         dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+        precision=_EXACT, preferred_element_type=jnp.float32)
     return acc[:, 0, :]
 
 
@@ -105,7 +130,7 @@ def _gather_rows(oh, table):
     with an (Np, Np) table -> (BC, L, Np)."""
     return jax.lax.dot_general(
         oh, table, dimension_numbers=(((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_EXACT, preferred_element_type=jnp.float32)
 
 
 def _gather_state(oh, x_f):
@@ -198,7 +223,7 @@ def _gumbel_stream(host_rng, ref, BC, Dp):
 
 def _logu_stream(host_rng, ref, BC):
     if host_rng:
-        return lambda s: ref[:, pl.ds(s, 1)]
+        return lambda s: _lane_at(ref[...], s)
 
     def dev(s):  # pragma: no cover - TPU-compiled path
         u = _uniform_from_bits(pltpu.prng_random_bits((BC, 128)))
@@ -254,7 +279,7 @@ def _sweep_kernel(*refs, n: int, D: int, S: int, Kp: int, scale: float,
 
     def substep(s, carry):
         x, acc = carry                                     # (BC,Np), (BC,1)
-        i_s = i_ref[:, pl.ds(s, 1)]                        # (BC, 1)
+        i_s = _lane_at(i_ref[...], s)                      # (BC, 1)
         oh_i = (iota_n == i_s).astype(jnp.float32)         # (BC, Np)
         w_row = _row_select(oh_i, W)                       # (BC, Np)
         # shared one-hot of the current state (stage 2 + stage 3 operand);
@@ -269,7 +294,7 @@ def _sweep_kernel(*refs, n: int, D: int, S: int, Kp: int, scale: float,
             alias_row = _row_select(oh_i, RA)
             j = _alias_row_draw(rand_u1(s), rand_u2(s), prob_row,
                                 alias_row, n)              # (BC, Kp)
-            b_s = b_ref[:, pl.ds(s, 1)]                    # (BC, 1)
+            b_s = _lane_at(b_ref[...], s)                  # (BC, 1)
             iota_k = jax.lax.broadcasted_iota(jnp.int32, (BC, Kp), 1)
             w_k = scale * (iota_k < b_s).astype(jnp.float32)
             # stage 2: draws -> per-site counts -> bucket energies over D
@@ -322,54 +347,49 @@ def _min_gibbs_kernel(*refs, n: int, D: int, S: int, Kp: int,
 
     BC, Np = x_ref.shape
     Dp = co_ref.shape[1]
-    DK = D * Kp                        # D candidate blocks of Kp draw lanes
     RP = rp_ref[...]
     RA = ra_ref[...].astype(jnp.float32)
     nprob = jnp.broadcast_to(np_ref[0:1, :], (BC, Np))
     nalias = jnp.broadcast_to(na_ref[0:1, :], (BC, Np)).astype(jnp.float32)
     iota_n = jax.lax.broadcasted_iota(jnp.int32, (BC, Np), 1)
     iota_d = jax.lax.broadcasted_iota(jnp.int32, (BC, Dp), 1)
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (BC, Kp), 1)
     lane_pad = iota_d >= D
-    # static lane -> (candidate, draw) decomposition of the DK draw lanes
-    ucand = jax.lax.broadcasted_iota(
-        jnp.int32, (BC, D, Kp), 1).reshape(BC, DK)
-    klane = jax.lax.broadcasted_iota(
-        jnp.int32, (BC, D, Kp), 2).reshape(BC, DK)
-    iota_dl = jax.lax.broadcasted_iota(jnp.int32, (BC, Dp, DK), 1)
-    oh_cand_t = (ucand[:, None, :] == iota_dl).astype(jnp.float32)
-    iota_ld = jax.lax.broadcasted_iota(jnp.int32, (BC, DK, Dp), 2)
-    oh_cand = (ucand[:, :, None] == iota_ld).astype(jnp.float32)
     if not host_rng:  # pragma: no cover - TPU-compiled path
         pltpu.prng_seed(seed_ref[0], pl.program_id(0))
-    rand_un = _uniform_stream(host_rng, un_ref if host_rng else None,
-                              BC, DK)
-    rand_una = _uniform_stream(host_rng, una_ref if host_rng else None,
-                               BC, DK)
-    rand_ur = _uniform_stream(host_rng, ur_ref if host_rng else None,
-                              BC, DK)
-    rand_ura = _uniform_stream(host_rng, ura_ref if host_rng else None,
-                               BC, DK)
+
+    def cand_draws(s, u):
+        """The four uniform blocks of candidate u's minibatch, (BC, Kp)."""
+        if host_rng:
+            return [r[:, s * D + u, :] for r in (un_ref, una_ref, ur_ref,
+                                                 ura_ref)]
+        return [_uniform_from_bits(  # pragma: no cover - TPU path
+            pltpu.prng_random_bits((BC, Kp))) for _ in range(4)]
     rand_gumbel = _gumbel_stream(host_rng, g_ref if host_rng else None,
                                  BC, Dp)
 
     def substep(s, carry):
         x, cache = carry                                   # (BC,Np), (BC,1)
-        i_s = i_ref[:, pl.ds(s, 1)]                        # (BC, 1)
-        # D independent global minibatches, one per candidate value: the
-        # candidate-u block occupies lanes [u*Kp, (u+1)*Kp)
-        a, b, oh_a, oh_b = _pair_draw(
-            rand_un(s), rand_una(s), rand_ur(s), rand_ura(s),
-            nprob, nalias, RP, RA, n)                      # (BC, DK)
+        i_s = _lane_at(i_ref[...], s)                      # (BC, 1)
         x_f = x.astype(jnp.float32)
-        xa = _gather_state(oh_a, x_f)
-        xb = _gather_state(oh_b, x_f)
-        # candidate substitution: endpoints hitting i_s read value u
-        xa = jnp.where(a == i_s, ucand, xa)
-        xb = jnp.where(b == i_s, ucand, xb)
-        b_s = b_ref[:, s, :].astype(jnp.float32)           # (BC, Dp)
-        b_l = _bucket(b_s, oh_cand_t).astype(jnp.int32)    # per-lane B_u
-        matchv = ((xa == xb) & (klane < b_l)).astype(jnp.float32)
-        cnt = _bucket(matchv, oh_cand)                     # (BC, Dp)
+        b_s = b_ref[:, s, :]                               # (BC, Dp) B_u
+
+        # D independent global minibatches, one per candidate value u, one
+        # Kp-lane block at a time (row s*D + u of the host streams)
+        def candidate(u, cnt):
+            a, b, oh_a, oh_b = _pair_draw(*cand_draws(s, u), nprob, nalias,
+                                          RP, RA, n)       # (BC, Kp)
+            xa = _gather_state(oh_a, x_f)
+            xb = _gather_state(oh_b, x_f)
+            # candidate substitution: endpoints hitting i_s read value u
+            xa = jnp.where(a == i_s, u, xa)
+            xb = jnp.where(b == i_s, u, xb)
+            match = (xa == xb) & (iota_k < _lane_at(b_s, u))
+            m = jnp.sum(match.astype(jnp.float32), axis=1, keepdims=True)
+            return jnp.where(iota_d == u, m, cnt)
+
+        cnt = jax.lax.fori_loop(0, D, candidate,
+                                jnp.zeros((BC, Dp), jnp.float32))
         eps = lscale * cnt
         xi = jnp.sum(jnp.where(iota_n == i_s, x, 0), axis=1,
                      keepdims=True)                        # (BC, 1)
@@ -431,7 +451,7 @@ def _double_min_kernel(*refs, n: int, D: int, S: int, K1p: int, K2p: int,
 
     def substep(s, carry):
         x, cache, acc = carry                    # (BC,Np), (BC,1), (BC,1)
-        i_s = i_ref[:, pl.ds(s, 1)]                        # (BC, 1)
+        i_s = _lane_at(i_ref[...], s)                      # (BC, 1)
         oh_i = (iota_n == i_s).astype(jnp.float32)
         # MGPMH proposal: local alias minibatch -> bucket energies.  The
         # scale is applied to the exact integer counts so the values are
@@ -439,7 +459,7 @@ def _double_min_kernel(*refs, n: int, D: int, S: int, K1p: int, K2p: int,
         prob_row = _row_select(oh_i, RP)
         alias_row = _row_select(oh_i, RA)
         j = _alias_row_draw(rand_u1(s), rand_u2(s), prob_row, alias_row, n)
-        b1_s = b1_ref[:, pl.ds(s, 1)]                      # (BC, 1)
+        b1_s = _lane_at(b1_ref[...], s)                    # (BC, 1)
         w_k = (iota_k1 < b1_s).astype(jnp.float32)
         iota_kn = jax.lax.broadcasted_iota(jnp.int32, (BC, K1p, Np), 2)
         oh_j = (j[:, :, None] == iota_kn).astype(jnp.float32)
@@ -458,7 +478,7 @@ def _double_min_kernel(*refs, n: int, D: int, S: int, K1p: int, K2p: int,
         yb = _gather_state(oh_b, x_f)
         ya = jnp.where(a == i_s, v, ya)
         yb = jnp.where(b == i_s, v, yb)
-        b2_s = b2_ref[:, pl.ds(s, 1)]                      # (BC, 1)
+        b2_s = _lane_at(b2_ref[...], s)                    # (BC, 1)
         m = jnp.sum(((ya == yb) & (iota_k2 < b2_s)).astype(jnp.float32),
                     axis=1, keepdims=True)
         xi_y = lscale2 * m                                 # (BC, 1)
@@ -530,6 +550,7 @@ def mgpmh_sweep_pallas(x, W, row_prob, row_alias, i_sites, B, u_idx, u_alias,
         out_shape=[jax.ShapeDtypeStruct((C, Np), jnp.int32),
                    jax.ShapeDtypeStruct((C, Dp), jnp.int32)],
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(x, W.astype(jnp.float32), row_prob.astype(jnp.float32),
       row_alias.astype(jnp.int32), i_sites.astype(jnp.int32),
       B.astype(jnp.int32), u_idx.astype(jnp.float32),
@@ -550,8 +571,6 @@ def mgpmh_sweep_pallas_rng(x, W, row_prob, row_alias, i_sites, B, seed,
     interpret mode (``prng_seed`` has no CPU lowering) — this is the
     ROADMAP's TPU-compiled bench entry point.
     """
-    if pltpu is None:  # pragma: no cover
-        raise NotImplementedError("in-kernel PRNG requires pallas TPU")
     C, Np = x.shape
     ins = [(x.shape, True), (W.shape, False), (row_prob.shape, False),
            (row_alias.shape, False), (i_sites.shape, True), (B.shape, True)]
@@ -567,6 +586,7 @@ def mgpmh_sweep_pallas_rng(x, W, row_prob, row_alias, i_sites, B, seed,
         out_shape=[jax.ShapeDtypeStruct((C, Np), jnp.int32),
                    jax.ShapeDtypeStruct((C, Dp), jnp.int32)],
         interpret=False,
+        compiler_params=_COMPILER_PARAMS,
     )(x, W.astype(jnp.float32), row_prob.astype(jnp.float32),
       row_alias.astype(jnp.int32), i_sites.astype(jnp.int32),
       B.astype(jnp.int32), seed.astype(jnp.int32))
@@ -596,6 +616,7 @@ def gibbs_sweep_pallas(x, W, i_sites, gumbel, *, n: int, D: int, S: int,
         out_shape=[jax.ShapeDtypeStruct((C, Np), jnp.int32),
                    jax.ShapeDtypeStruct((C, Dp), jnp.int32)],
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(x, W.astype(jnp.float32), i_sites.astype(jnp.int32),
       gumbel.astype(jnp.float32))
 
@@ -612,14 +633,14 @@ def min_gibbs_sweep_pallas(x, node_prob, node_alias, row_prob, row_alias,
 
     x (C, Np) i32; node_prob/node_alias (8, Np) replicated rows;
     row_prob/row_alias (Np, Np); i_sites (C, Sp); B (C, Sp', Dp) i32;
-    u_node/u_nacc/u_row/u_racc (C, Sp', D*Kp) f32 — candidate u's draws in
-    lanes [u*Kp, (u+1)*Kp); gumbel (C, Sp', Dp) f32; cache (C, Dp) f32
-    (lane-broadcast).  Returns (x_out (C, Np) i32, cache_out (C, Dp) f32 —
-    value broadcast over lanes).
+    u_node/u_nacc/u_row/u_racc (C, SD', Kp) f32 — sub-step s's draws for
+    candidate u in row s*D + u (SD' = S*D rounded up to 8); gumbel
+    (C, Sp', Dp) f32; cache (C, Dp) f32 (lane-broadcast).  Returns
+    (x_out (C, Np) i32, cache_out (C, Dp) f32 — value broadcast over
+    lanes).
     """
     C, Np = x.shape
-    DK = u_node.shape[-1]
-    Kp = DK // D
+    Kp = u_node.shape[-1]
     Dp = gumbel.shape[-1]
     ins = [(x.shape, True), (node_prob.shape, False),
            (node_alias.shape, False), (row_prob.shape, False),
@@ -637,6 +658,7 @@ def min_gibbs_sweep_pallas(x, node_prob, node_alias, row_prob, row_alias,
         out_shape=[jax.ShapeDtypeStruct((C, Np), jnp.int32),
                    jax.ShapeDtypeStruct((C, Dp), jnp.float32)],
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(x, node_prob.astype(jnp.float32), node_alias.astype(jnp.int32),
       row_prob.astype(jnp.float32), row_alias.astype(jnp.int32),
       i_sites.astype(jnp.int32), B.astype(jnp.int32),
@@ -657,8 +679,6 @@ def min_gibbs_sweep_pallas_rng(x, node_prob, node_alias, row_prob, row_alias,
     stay host-drawn.  ``seed`` is a (1,) int32; otherwise the pre-padded
     contract of ``min_gibbs_sweep_pallas``.  TPU-compiled-only.
     """
-    if pltpu is None:  # pragma: no cover
-        raise NotImplementedError("in-kernel PRNG requires pallas TPU")
     C, Np = x.shape
     ins = [(x.shape, True), (node_prob.shape, False),
            (node_alias.shape, False), (row_prob.shape, False),
@@ -676,6 +696,7 @@ def min_gibbs_sweep_pallas_rng(x, node_prob, node_alias, row_prob, row_alias,
         out_shape=[jax.ShapeDtypeStruct((C, Np), jnp.int32),
                    jax.ShapeDtypeStruct((C, Dp), jnp.float32)],
         interpret=False,
+        compiler_params=_COMPILER_PARAMS,
     )(x, node_prob.astype(jnp.float32), node_alias.astype(jnp.int32),
       row_prob.astype(jnp.float32), row_alias.astype(jnp.int32),
       i_sites.astype(jnp.int32), B.astype(jnp.int32),
@@ -724,6 +745,7 @@ def double_min_sweep_pallas(x, row_prob, row_alias, node_prob, node_alias,
                    jax.ShapeDtypeStruct((C, Dp), jnp.float32),
                    jax.ShapeDtypeStruct((C, Dp), jnp.int32)],
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(x, row_prob.astype(jnp.float32), row_alias.astype(jnp.int32),
       node_prob.astype(jnp.float32), node_alias.astype(jnp.int32),
       i_sites.astype(jnp.int32), B1.astype(jnp.int32),
@@ -747,8 +769,6 @@ def double_min_sweep_pallas_rng(x, row_prob, row_alias, node_prob,
     (1,) int32; otherwise the pre-padded contract of
     ``double_min_sweep_pallas``.  TPU-compiled-only.
     """
-    if pltpu is None:  # pragma: no cover
-        raise NotImplementedError("in-kernel PRNG requires pallas TPU")
     C, Np = x.shape
     ins = [(x.shape, True), (row_prob.shape, False),
            (row_alias.shape, False), (node_prob.shape, False),
@@ -769,6 +789,7 @@ def double_min_sweep_pallas_rng(x, row_prob, row_alias, node_prob,
                    jax.ShapeDtypeStruct((C, Dp), jnp.float32),
                    jax.ShapeDtypeStruct((C, Dp), jnp.int32)],
         interpret=False,
+        compiler_params=_COMPILER_PARAMS,
     )(x, row_prob.astype(jnp.float32), row_alias.astype(jnp.int32),
       node_prob.astype(jnp.float32), node_alias.astype(jnp.int32),
       i_sites.astype(jnp.int32), B1.astype(jnp.int32),

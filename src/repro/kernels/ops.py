@@ -1,5 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels with shape padding and
-implementation dispatch (pallas on TPU / interpret elsewhere / jnp oracle).
+implementation dispatch: ``impl='pallas'`` runs the kernel (compiled with
+Mosaic on a TPU, in interpret mode anywhere else — see :func:`interpret`),
+``impl='jnp'`` the oracle.  The caller chooses; ``engine.make`` owns the
+choice for the samplers.
 """
 from __future__ import annotations
 
@@ -7,16 +10,31 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .minibatch_energy import bucket_energy_pallas
 from .flash_attention import flash_attention_pallas
-from .fused_sweep import (mgpmh_sweep_pallas, gibbs_sweep_pallas,
-                          min_gibbs_sweep_pallas, double_min_sweep_pallas)
+from .fused_sweep import (VMEM_LIMIT_BYTES, mgpmh_sweep_pallas,
+                          gibbs_sweep_pallas, min_gibbs_sweep_pallas,
+                          double_min_sweep_pallas)
 from .ref import (bucket_energy_ref, mgpmh_sweep_ref, gibbs_sweep_ref,
                   min_gibbs_sweep_ref, double_min_sweep_ref)
 
 __all__ = ["bucket_energy", "flash_attention", "mgpmh_sweep", "gibbs_sweep",
-           "min_gibbs_sweep", "double_min_sweep"]
+           "min_gibbs_sweep", "double_min_sweep", "interpret",
+           "KernelTooLarge", "sweep_vmem_bytes", "check_sweep_vmem"]
+
+
+def interpret() -> bool:
+    """True where the Pallas kernels run in interpret mode: on any backend
+    but a TPU, where they compile with Mosaic (``Engine.describe()``
+    reports it)."""
+    return jax.default_backend() != "tpu"
+
+
+def _check_impl(impl: str):
+    if impl not in ("pallas", "jnp"):
+        raise ValueError(f"impl must be 'pallas' or 'jnp', got {impl!r}")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -38,16 +56,15 @@ def _named(scope: str):
 
 
 @functools.partial(jax.jit, static_argnames=("D", "impl"))
-def bucket_energy(w: jax.Array, v: jax.Array, D: int,
-                  impl: str = "auto") -> jax.Array:
+def bucket_energy(w: jax.Array, v: jax.Array, D: int, *,
+                  impl: str) -> jax.Array:
     """E[c,u] = sum_k w[c,k] * 1[v[c,k]==u]; see kernels/ref.py.
 
-    impl: 'auto'   — pallas (compiled on TPU, interpret elsewhere),
-          'pallas' — force the kernel (interpret off-TPU),
-          'jnp'    — pure-jnp oracle.
-    Handles arbitrary (C, K): pads C to 8 and K to the block size with
-    zero weights / out-of-range values.
+    impl: 'pallas' — the kernel (interpret mode off-TPU), 'jnp' — the
+    oracle.  Handles arbitrary (C, K): pads C to 8 and K to the block size
+    with zero weights / out-of-range values.
     """
+    _check_impl(impl)
     if impl == "jnp":
         return bucket_energy_ref(w, v, D)
     C, K = w.shape
@@ -64,8 +81,8 @@ def bucket_energy(w: jax.Array, v: jax.Array, D: int,
         pad = ((0, Cp - C), (0, Kp - K))
         wp = jnp.pad(wp, pad)                                # zero weight
         vp = jnp.pad(vp, pad, constant_values=D)             # D = no bucket
-    interpret = jax.default_backend() != "tpu"
-    out = bucket_energy_pallas(wp, vp, D, bc=bc, bk=bk, interpret=interpret)
+    out = bucket_energy_pallas(wp, vp, D, bc=bc, bk=bk,
+                               interpret=interpret())
     return out[:C, :D] if (Cp, dp) != (C, D) else out
 
 
@@ -97,6 +114,74 @@ def _pad3(a, Cp, Lp):
     return jnp.pad(a, ((0, Cp - C), (0, Sp - S), (0, Lp - L)))
 
 
+class KernelTooLarge(ValueError):
+    """A fused sweep kernel's VMEM working set exceeds the limit it
+    compiles under (``fused_sweep.VMEM_LIMIT_BYTES``)."""
+
+
+def _tile_bytes(*shape) -> int:
+    """Bytes of a 4-byte block in the (8, 128) tiled VMEM layout."""
+    *lead, rows, cols = shape
+    return (4 * int(np.prod(lead, dtype=np.int64)) * _round_up(rows, 8)
+            * _round_up(cols, 128))
+
+
+def sweep_vmem_bytes(kernel: str, *, n: int, D: int, S: int, K: int = 0,
+                     K2: int = 0) -> int:
+    """Upper estimate of the VMEM one fused sweep kernel takes on a chain
+    block: every pipelined input/output block twice (double buffering),
+    plus the in-kernel working set: one copy of an (Np, Np) table (two for
+    gibbs, which at Np = 2048 copies W whole) and, per site lane, 20 KiB
+    for the (BC, Np, Dp) temporaries of the proposal kernels, 150 bytes per
+    local alias draw lane and 256 per two-stage pair draw lane (the
+    f32-exact one-hot gathers split their operands).  The constants bound
+    what Mosaic allocated for v5e at Np in {512, 1024} and draw widths in
+    {128, 256} (CHANGES.md).
+
+    ``kernel`` is 'gibbs' | 'mgpmh' | 'min-gibbs' | 'doublemin'; ``K`` is
+    the draw capacity (doublemin: the proposal's, ``K2`` the second
+    batch's); ``S`` the sub-steps per call."""
+    _, Np, Sp, Dp = _sweep_pads(8, n, S, D)
+    S8 = _round_up(S, 8)
+    Kp, K2p = (max(128, _round_up(k, 128)) for k in (K, K2))
+    row, lane = _tile_bytes(8, Np), _tile_bytes(8, Sp)
+    table, out = _tile_bytes(Np, Np), _tile_bytes(8, Dp)
+    gumbel = _tile_bytes(8, S8, Dp)
+    if kernel == "gibbs":
+        blocks = 2 * row + table + lane + gumbel + out
+        copies, site, alias_lanes, pair_lanes = 2, 0, 0, 0
+    elif kernel == "mgpmh":
+        blocks = (2 * row + 3 * table + 3 * lane + 2 * _tile_bytes(8, S8, Kp)
+                  + gumbel + out)
+        copies, site, alias_lanes, pair_lanes = 1, 20480, Kp, 0
+    elif kernel == "min-gibbs":
+        blocks = (4 * row + 2 * table + lane + 2 * gumbel
+                  + 4 * _tile_bytes(8, S * D, Kp) + 2 * out)
+        copies, site, alias_lanes, pair_lanes = 1, 0, 0, Kp
+    elif kernel == "doublemin":
+        blocks = (4 * row + 2 * table + 4 * lane + 2 * _tile_bytes(8, S8, Kp)
+                  + gumbel + 4 * _tile_bytes(8, S8, K2p) + 3 * out)
+        copies, site, alias_lanes, pair_lanes = 1, 20480, Kp, K2p
+    else:
+        raise ValueError(f"unknown fused sweep kernel {kernel!r}")
+    return (2 * blocks + copies * table
+            + Np * (site + 150 * alias_lanes + 256 * pair_lanes))
+
+
+def check_sweep_vmem(kernel: str, **dims) -> None:
+    """Raise :class:`KernelTooLarge`, naming the bytes, when the fused
+    ``kernel`` cannot hold ``dims`` (see :func:`sweep_vmem_bytes`) in VMEM.
+    Past the limit Mosaic refuses the kernel, or spends minutes and tens of
+    GiB of host memory compiling before it does."""
+    need = sweep_vmem_bytes(kernel, **dims)
+    if need > VMEM_LIMIT_BYTES:
+        shape = ", ".join(f"{k}={v}" for k, v in dims.items())
+        raise KernelTooLarge(
+            f"the fused {kernel} sweep kernel needs about {need} bytes of "
+            f"VMEM at {shape}, over its limit of {VMEM_LIMIT_BYTES} bytes; "
+            f"use backend='jnp' or 'dist' (or a smaller lam)")
+
+
 def _pad_square(t, Np):
     n = t.shape[0]
     if n == Np:
@@ -107,16 +192,14 @@ def _pad_square(t, Np):
 @functools.partial(jax.jit, static_argnames=("D", "scale", "impl"))
 @_named("repro.kernel/mgpmh_sweep")
 def mgpmh_sweep(x, W, row_prob, row_alias, i_sites, B, u_idx, u_alias,
-                gumbel, logu, *, D: int, scale: float, impl: str = "auto"):
+                gumbel, logu, *, D: int, scale: float, impl: str):
     """S fused sequential MGPMH site updates per chain (see kernels/ref.py
     ``mgpmh_sweep_ref`` for exact semantics).
 
     x (C, n) i32; W/row_prob/row_alias (n, n); i_sites/B/logu (C, S);
     u_idx/u_alias (C, S, K) f32 uniforms; gumbel (C, S, D) f32.
     ``scale`` = L/lambda.
-    impl: 'auto'   — kernel on TPU, jnp oracle elsewhere (the interpret-mode
-                     kernel is orders of magnitude slower than the oracle),
-          'pallas' — force the kernel (interpret off-TPU),
+    impl: 'pallas' — the kernel (interpret mode off-TPU),
           'jnp'    — the oracle (kernels/ref.py).
     Returns (x_out (C, n) i32, accepts (C,) i32).
 
@@ -124,9 +207,8 @@ def mgpmh_sweep(x, W, row_prob, row_alias, i_sites, B, u_idx, u_alias,
     masked lane), draws to 128 with zero weight, the sub-step axis of the
     (C, S) streams to 128 lanes (the kernel only loops the real S).
     """
-    if impl not in ("auto", "pallas", "jnp"):
-        raise ValueError(f"unknown impl: {impl!r}")
-    if impl == "jnp" or (impl == "auto" and jax.default_backend() != "tpu"):
+    _check_impl(impl)
+    if impl == "jnp":
         return mgpmh_sweep_ref(x, W, row_prob, row_alias, i_sites, B,
                                u_idx, u_alias, gumbel, logu, D, scale)
     C, n = x.shape
@@ -143,22 +225,21 @@ def mgpmh_sweep(x, W, row_prob, row_alias, i_sites, B, u_idx, u_alias,
         _pad2(B, Cp, Sp), _pad3(u_idx, Cp, Kp), _pad3(u_alias, Cp, Kp),
         _pad3(gumbel, Cp, Dp), _pad2(logu, Cp, Sp),
         n=n, D=D, S=S, scale=scale,
-        interpret=jax.default_backend() != "tpu")
+        interpret=interpret())
     return out_x[:C, :n], out_acc[:C, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("D", "impl"))
 @_named("repro.kernel/gibbs_sweep")
-def gibbs_sweep(x, W, i_sites, gumbel, *, D: int, impl: str = "auto"):
+def gibbs_sweep(x, W, i_sites, gumbel, *, D: int, impl: str):
     """S fused sequential vanilla-Gibbs site updates per chain (exact
     conditionals; see kernels/ref.py ``gibbs_sweep_ref``).
 
     x (C, n) i32; W (n, n); i_sites (C, S); gumbel (C, S, D).
     Returns x_out (C, n) i32.  impl and padding as in mgpmh_sweep.
     """
-    if impl not in ("auto", "pallas", "jnp"):
-        raise ValueError(f"unknown impl: {impl!r}")
-    if impl == "jnp" or (impl == "auto" and jax.default_backend() != "tpu"):
+    _check_impl(impl)
+    if impl == "jnp":
         return gibbs_sweep_ref(x, W, i_sites, gumbel, D)
     C, n = x.shape
     S = i_sites.shape[1]
@@ -169,20 +250,20 @@ def gibbs_sweep(x, W, i_sites, gumbel, *, D: int, impl: str = "auto"):
     out_x, _ = gibbs_sweep_pallas(
         xp, _pad_square(W, Np), _pad2(i_sites, Cp, Sp),
         _pad3(gumbel, Cp, Dp), n=n, D=D, S=S,
-        interpret=jax.default_backend() != "tpu")
+        interpret=interpret())
     return out_x[:C, :n]
 
 
-def _pad_cand_streams(streams, Cp, D, Kp):
-    """Pad (C, S, D, K) per-candidate draw streams to (Cp, S8, D*Kp): draws
-    to Kp lanes per candidate block, then blocks flattened onto the lane
-    axis (candidate u occupies lanes [u*Kp, (u+1)*Kp))."""
+def _pad_cand_streams(streams, Cp, Kp):
+    """Pad (C, S, D, K) per-candidate draw streams to (Cp, SD8, Kp): draws
+    to Kp lanes, (sub-step, candidate) flattened onto the sublane axis
+    (sub-step s, candidate u in row s*D + u)."""
     out = []
     for u in streams:
         C, S, D_, K = u.shape
         if K != Kp:
             u = jnp.pad(u, ((0, 0), (0, 0), (0, 0), (0, Kp - K)))
-        out.append(_pad3(u.reshape(C, S, D_ * Kp), Cp, D_ * Kp))
+        out.append(_pad3(u.reshape(C, S * D_, Kp), Cp, Kp))
     return out
 
 
@@ -207,7 +288,7 @@ def _pad_node_table(t, n, Np):
 @_named("repro.kernel/min_gibbs_sweep")
 def min_gibbs_sweep(x, node_prob, node_alias, row_prob, row_alias, i_sites,
                     B, u_node, u_nacc, u_row, u_racc, gumbel, cache, *,
-                    D: int, lscale: float, impl: str = "auto"):
+                    D: int, lscale: float, impl: str):
     """S fused sequential MIN-Gibbs site updates per chain with the cached
     energy estimate threaded through (see kernels/ref.py
     ``min_gibbs_sweep_ref`` for exact semantics).
@@ -219,12 +300,11 @@ def min_gibbs_sweep(x, node_prob, node_alias, row_prob, row_alias, i_sites,
     Returns (x_out (C, n) i32, cache_out (C,) f32).
 
     Padding: chains to 8, sites to 128 lanes with x = D, per-candidate draw
-    blocks to Kp=128-multiples with zero uniforms (masked by B), candidate
-    blocks flattened onto one D*Kp lane axis.
+    blocks to Kp=128-multiples with zero uniforms (masked by B), the
+    (sub-step, candidate) axes flattened onto one sublane axis.
     """
-    if impl not in ("auto", "pallas", "jnp"):
-        raise ValueError(f"unknown impl: {impl!r}")
-    if impl == "jnp" or (impl == "auto" and jax.default_backend() != "tpu"):
+    _check_impl(impl)
+    if impl == "jnp":
         return min_gibbs_sweep_ref(x, node_prob, node_alias, row_prob,
                                    row_alias, i_sites, B, u_node, u_nacc,
                                    u_row, u_racc, gumbel, cache, D, lscale)
@@ -237,14 +317,14 @@ def min_gibbs_sweep(x, node_prob, node_alias, row_prob, row_alias, i_sites,
     if (Cp, Np) != (C, n):
         xp = jnp.pad(x, ((0, Cp - C), (0, Np - n)), constant_values=D)
     un, una, ur, ura = _pad_cand_streams([u_node, u_nacc, u_row, u_racc],
-                                         Cp, D, Kp)
+                                         Cp, Kp)
     out_x, out_cache = min_gibbs_sweep_pallas(
         xp, _pad_node_table(node_prob, n, Np),
         _pad_node_table(node_alias, n, Np), _pad_square(row_prob, Np),
         _pad_square(row_alias, Np), _pad2(i_sites, Cp, Sp),
         _pad3(B, Cp, Dp), un, una, ur, ura, _pad3(gumbel, Cp, Dp),
         _pad_cache(cache, Cp, Dp), n=n, D=D, S=S, lscale=lscale,
-        interpret=jax.default_backend() != "tpu")
+        interpret=interpret())
     return out_x[:C, :n], out_cache[:C, 0]
 
 
@@ -254,7 +334,7 @@ def min_gibbs_sweep(x, node_prob, node_alias, row_prob, row_alias, i_sites,
 def double_min_sweep(x, row_prob, row_alias, node_prob, node_alias, i_sites,
                      B1, u_idx, u_alias, gumbel, B2, u_node, u_nacc, u_row,
                      u_racc, logu, cache, *, D: int, scale1: float,
-                     lscale2: float, impl: str = "auto"):
+                     lscale2: float, impl: str):
     """S fused sequential DoubleMIN site updates per chain with the cached
     xi_x threaded through (see kernels/ref.py ``double_min_sweep_ref``).
 
@@ -265,9 +345,8 @@ def double_min_sweep(x, row_prob, row_alias, node_prob, node_alias, i_sites,
     as in mgpmh_sweep.  Returns (x_out (C, n) i32, cache_out (C,) f32,
     accepts (C,) i32).
     """
-    if impl not in ("auto", "pallas", "jnp"):
-        raise ValueError(f"unknown impl: {impl!r}")
-    if impl == "jnp" or (impl == "auto" and jax.default_backend() != "tpu"):
+    _check_impl(impl)
+    if impl == "jnp":
         return double_min_sweep_ref(x, row_prob, row_alias, node_prob,
                                     node_alias, i_sites, B1, u_idx, u_alias,
                                     gumbel, B2, u_node, u_nacc, u_row,
@@ -291,7 +370,7 @@ def double_min_sweep(x, row_prob, row_alias, node_prob, node_alias, i_sites,
         _pad3(u_nacc, Cp, K2p), _pad3(u_row, Cp, K2p),
         _pad3(u_racc, Cp, K2p), _pad2(logu, Cp, Sp),
         _pad_cache(cache, Cp, Dp), n=n, D=D, S=S, scale1=scale1,
-        lscale2=lscale2, interpret=jax.default_backend() != "tpu")
+        lscale2=lscale2, interpret=interpret())
     return out_x[:C, :n], out_cache[:C, 0], out_acc[:C, 0]
 
 
@@ -322,6 +401,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         vf = jnp.pad(vf, ((0, 0), (0, pk), (0, 0)))
     out = flash_attention_pallas(
         qf, kf, vf, window=window, causal=causal, sk_valid=Sk,
-        interpret=jax.default_backend() != "tpu")
+        interpret=interpret())
     out = out[:, :Sq].reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
     return out

@@ -300,7 +300,6 @@ def run_gibbs_cell(name: str, *, multi_pod: bool, out_dir: str,
     roofline term).
     """
     from ..runtime import dist_gibbs as DG
-    from jax.experimental.shard_map import shard_map
 
     mesh_tag = "pod2x16x16" if multi_pod else "pod16x16"
     os.makedirs(os.path.join(out_dir, mesh_tag), exist_ok=True)
@@ -354,11 +353,11 @@ def run_gibbs_cell(name: str, *, multi_pod: bool, out_dir: str,
             x=P(dp, None), cache=P(dp), key=P(dp),
             accepts=P(dp), marg=P(dp, MP_AXIS, None), count=P())
 
-        smapped = shard_map(
+        smapped = jax.shard_map(
             lambda st, sh: step(st, sh), mesh=mesh,
             in_specs=(state_specs, shard_specs),
             out_specs=state_specs,
-            check_rep=False)
+            check_vma=False)
 
         dp_total = 1
         for a in dp:

@@ -26,6 +26,7 @@ from __future__ import annotations
 import argparse
 import os
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,10 +35,8 @@ import numpy as np
 from .. import obs
 from ..core import engine as engine_lib
 from ..checkpoint import checkpoint as ckpt
-from .mesh import make_device_mesh, compat_shard_map
-
-# legacy alias (pre-engine consumers imported the compat wrapper from here)
-shard_map = compat_shard_map
+from .compile_cache import use_compile_cache
+from .mesh import make_device_mesh
 
 
 def _engine_factory(config: str, sweep: int, mp_shards: int, backend: str,
@@ -65,10 +64,10 @@ def _engine_factory(config: str, sweep: int, mp_shards: int, backend: str,
 
 
 def _build_engine(config: str, engine: str, sweep: int, mp_shards: int,
-                  backend: str, adaptive: bool):
+                  backend: str, adaptive: bool, params: dict):
     make_engine, g = _engine_factory(config, sweep, mp_shards, backend,
                                      adaptive)
-    return make_engine(engine, list(jax.devices())), g
+    return make_engine(engine, list(jax.devices()), **params), g
 
 
 def run_supervised(config: str, engine: str, steps: int, chains: int,
@@ -105,11 +104,17 @@ def run_supervised(config: str, engine: str, steps: int, chains: int,
 def run(config: str, engine: str, steps: int, chains: int,
         ckpt_dir: str = "", log_every: int = 2000, mp_shards: int = 0,
         seed: int = 0, sweep: int = 0, backend: str = "dist",
-        adaptive: bool = False, telemetry: bool = False):
+        adaptive: bool = False, telemetry: bool = False,
+        params: Optional[dict] = None) -> dict:
+    """Run ``steps`` sweeps of ``engine`` on workload ``config``; logs
+    every ``log_every`` steps.  ``params`` go to ``engine.make`` (lam,
+    capacity, ...).  Returns the final ``state``, the engine's
+    ``describe()`` and the last logged ``marg_err`` / ``acceptance`` /
+    ``updates_per_sec``."""
     from .. import diagnostics as diag
 
     eng, g = _build_engine(config, engine, sweep, mp_shards, backend,
-                           adaptive)
+                           adaptive, params or {})
     upd_per_step = eng.updates_per_call
     dist = eng.backend == "dist"
     rec = obs.get_recorder()
@@ -132,6 +137,7 @@ def run(config: str, engine: str, steps: int, chains: int,
 
     t0 = time.time()
     last_logged = 0
+    out = {"engine": eng.describe()}
     for s in range(start, steps):
         # span brackets one compiled sweep launch (dispatch only — the
         # host read below at the log boundary is the loop's only sync)
@@ -155,6 +161,7 @@ def run(config: str, engine: str, steps: int, chains: int,
                 float(np.asarray(st.accepts).mean()) / (cnt * upd_per_step))
             rate = ((s + 1 - start) * chains * upd_per_step
                     / (time.time() - t0))
+            out.update(marg_err=err, acceptance=acc, updates_per_sec=rate)
             line = (f"[gibbs] step {s+1:7d} marg_err={err:.4f} "
                     f"acc={acc:.3f} {rate/1e3:.1f}k updates/s")
             if tel is not None:
@@ -174,7 +181,8 @@ def run(config: str, engine: str, steps: int, chains: int,
             rec.snapshot()
             if ckpt_dir:
                 ckpt.save(ckpt_dir, s + 1, st if dist else (st, marg))
-    return st
+    out["state"] = st
+    return out
 
 
 def main():
@@ -232,6 +240,7 @@ def main():
                  f"engines, not {args.engine!r}")
     if args.fault_plan and not args.supervise:
         ap.error("--fault-plan requires --supervise")
+    use_compile_cache()
     rec = obs.configure(metrics_dir=args.metrics_dir or None,
                         trace_path=args.trace or None,
                         profile_dir=args.profile or None,

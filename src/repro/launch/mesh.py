@@ -11,45 +11,16 @@ import jax
 import numpy as np
 
 __all__ = ["make_production_mesh", "make_auto_mesh", "make_device_mesh",
-           "auto_axis_types", "compat_shard_map", "dp_axes", "MP_AXIS"]
+           "dp_axes", "MP_AXIS"]
 
 MP_AXIS = "model"
 
 
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """Version-compatible shard_map: ``jax.shard_map`` (jax >= 0.8, with
-    ``check_vma=False``) or the experimental fallback (``check_rep=False``).
-    """
-    try:
-        from jax import shard_map as _sm                   # jax >= 0.8
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-
-
-def auto_axis_types(n_axes: int) -> dict:
-    """Version-compatible ``axis_types`` kwargs for ``jax.make_mesh``.
-
-    Newer jax exposes ``jax.sharding.AxisType`` and expects explicit
-    axis types; older releases have neither the enum nor the kwarg.
-    Returns ``{"axis_types": (Auto,) * n_axes}`` when available, else ``{}``
-    — callers splat it: ``jax.make_mesh(shape, axes, **auto_axis_types(2))``.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_auto_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types when the jax version has them."""
-    try:
-        return jax.make_mesh(shape, axes, **auto_axis_types(len(axes)))
-    except TypeError:                      # older jax without axis_types kwarg
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` over all devices with Auto axis types."""
+    return jax.make_mesh(
+        shape, axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_device_mesh(shape, axes, devices) -> jax.sharding.Mesh:

@@ -39,6 +39,7 @@ from .. import obs
 from ..core import engine as engine_lib
 from ..diagnostics.freshness import FreshnessPolicy
 from ..serving import AdmissionPolicy, BreakerPolicy, ChainPool, Query
+from .compile_cache import use_compile_cache
 
 
 def _demo_queries(workload: str, graph, n: int, seed: int) -> List[Query]:
@@ -334,6 +335,7 @@ def main():
     if args.fault_plan and not args.supervise:
         ap.error("--fault-plan requires --supervise")
 
+    use_compile_cache()
     rec = obs.configure(metrics_dir=args.metrics_dir or None,
                         trace_path=args.trace or None,
                         profile_dir=args.profile or None,
@@ -376,6 +378,10 @@ def main():
         with open(args.out, "w") as f:
             json.dump(res, f, indent=1)
         print(f"[serve] wrote {args.out}")
+    n_err = res["status_counts"].get("error", 0)
+    if n_err:
+        raise SystemExit(f"[serve] {n_err} of {res['n_queries']} answers "
+                         f"failed with status 'error'")
 
 
 if __name__ == "__main__":

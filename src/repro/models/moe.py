@@ -44,7 +44,6 @@ def moe_ffn_sharded(x: jax.Array, params: dict, *, top_k: int,
     d_ff/mp slice of every expert (TP) — one psum over "model" combines
     outputs, the same collective the dense MLP's TP already pays.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp = tuple(dp_axes)
@@ -69,8 +68,8 @@ def moe_ffn_sharded(x: jax.Array, params: dict, *, top_k: int,
                          mp_axis=mp_axis, num_experts=E)
         return jax.lax.psum(out, mp_axis)
 
-    return shard_map(body, mesh=mesh, in_specs=(x_spec, w_specs),
-                     out_specs=x_spec, check_rep=False)(x, params)
+    return jax.shard_map(body, mesh=mesh, in_specs=(x_spec, w_specs),
+                         out_specs=x_spec, check_vma=False)(x, params)
 
 
 def _moe_local(xb, pw, *, top_k, capacity_factor, ep, mp_axis, num_experts):

@@ -44,9 +44,7 @@ def compressed_psum_mean(x: jax.Array, axis_name: str,
     (mean, new_err); ``err`` is this shard's residual from the previous call
     (same shape as x).
     """
-    # jax.lax.axis_size only exists in newer jax; psum(1) is the portable
-    # way to read the bound axis size
-    n = getattr(jax.lax, "axis_size", lambda a: jax.lax.psum(1, a))(axis_name)
+    n = jax.lax.axis_size(axis_name)
     xe = x + err
     q, scale = quantize_int8(xe)
     new_err = xe - dequantize_int8(q, scale)

@@ -69,6 +69,11 @@ __all__ = ["ShardedMatchGraph", "DistState", "DistAdaptiveState",
            "shard_specs", "state_specs", "adaptive_state_specs",
            "psum_footprint", "DIST_ALGOS"]
 
+# The energy contractions below are exact sums of weights picked by
+# one-hots; at XLA's default TPU precision (one bf16 pass) the weights
+# would round, so they contract at full f32 precision.
+_EXACT = jax.lax.Precision.HIGHEST
+
 DIST_ALGOS = ("gibbs", "mgpmh", "min-gibbs", "doublemin")
 
 
@@ -311,7 +316,7 @@ def _exact_partials(gs, sh, oh_loc, i, shard_idx):
     """
     C, S = i.shape
     w_rows = sh["W_cols"][i]                            # (C, S, n_loc)
-    exact0 = jnp.einsum("csn,cnd->csd", w_rows, oh_loc)
+    exact0 = jnp.einsum("csn,cnd->csd", w_rows, oh_loc, precision=_EXACT)
     off = shard_idx * gs.n_loc
     owned = (i >= off) & (i < off + gs.n_loc)           # (C, S) site t
     loc_t = jnp.broadcast_to(
@@ -347,7 +352,7 @@ def _proposal_partials(gs, sh, oh_loc, i, key, lam, capacity, shard_idx,
         j_loc].add(w)
     # eps0[c,s,d] = sum_q cnt_loc[c,s,q] d(x0_loc[q], d): the counts
     # already hold the whole minibatch, no per-draw gather needed
-    eps0 = jnp.einsum("csq,cqd->csd", cnt_loc, oh_loc)
+    eps0 = jnp.einsum("csq,cqd->csd", cnt_loc, oh_loc, precision=_EXACT)
     if exact_aux is not None:
         _, owned, loc_t = exact_aux
     else:
@@ -548,7 +553,8 @@ def make_dist_sweep(gs: ShardedMatchGraph, algo: str, sweep_len: int, *,
             """base + coupling · (one-hot(current) − one-hot(entry))."""
             delta = (jax.nn.one_hot(vals_cur, D, dtype=jnp.float32)
                      - oh0) * nodup                         # (C, S, D)
-            return base_s + jnp.einsum("ct,ctd->cd", coup_s, delta)
+            return base_s + jnp.einsum("ct,ctd->cd", coup_s, delta,
+                                       precision=_EXACT)
 
         def substep(carry, s):
             x, vals_cur, cache, acc = carry
@@ -647,7 +653,8 @@ def make_dist_chromatic_sweep(gs: ShardedMatchGraph, colors, *,
             oh_loc = jax.nn.one_hot(_x_cols(x, shard_idx, n_loc), D,
                                     dtype=jnp.float32)
             eps = jax.lax.psum(
-                jnp.einsum("nl,cld->cnd", sh["W_cols"], oh_loc), mp_axis)
+                jnp.einsum("nl,cld->cnd", sh["W_cols"], oh_loc,
+                           precision=_EXACT), mp_axis)
             gumbel = jax.random.gumbel(kv, (C, n, D))
             v = gibbs_select(eps, gumbel)
             x = jnp.where(colors_j[None, :] == c, v, x)
@@ -729,7 +736,8 @@ def make_chromatic_gibbs_step(g: MatchGraph, colors: np.ndarray):
     def step(x, key, color):
         kv, = jax.random.split(key, 1)
         onehot = jax.nn.one_hot(x, D, dtype=jnp.float32)       # (C, n, D)
-        eps = jnp.einsum("ij,cjd->cid", g.W, onehot)           # all cond energies
+        eps = jnp.einsum("ij,cjd->cid", g.W, onehot,   # all cond energies
+                         precision=_EXACT)
         v = jax.random.categorical(kv, eps, axis=-1).astype(jnp.int32)
         upd = (colors_j[None, :] == color)
         return jnp.where(upd, v, x)

@@ -68,7 +68,6 @@ def test_compressed_psum_mean():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.runtime.compression import compressed_psum_mean
 
         from repro.launch.mesh import make_auto_mesh
@@ -80,9 +79,10 @@ def test_compressed_psum_mean():
         def body(xv, ev):
             mean, err = compressed_psum_mean(xv[0], "data", ev[0])
             return mean, err[None]           # err stays per-shard
-        f = shard_map(body, mesh=mesh,
-                      in_specs=(P("data", None), P("data", None)),
-                      out_specs=(P(None), P("data", None)), check_rep=False)
+        f = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P("data", None), P("data", None)),
+                          out_specs=(P(None), P("data", None)),
+                          check_vma=False)
         with mesh:
             mean, err = f(x, err0)
         got = np.asarray(mean)

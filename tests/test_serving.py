@@ -259,3 +259,24 @@ def test_pool_lane_lru_and_validation():
                                               for s in range(24)))])
     with pytest.raises(ValueError, match="cannot serve"):
         pool.register("potts-20x20", engine="local-gibbs", backend="jnp")
+
+
+def test_serve_main_exits_nonzero_on_error_answers(monkeypatch, capsys):
+    """The pool turns a failing sweep into ``status='error'`` answers; the
+    serve launcher then exits non-zero instead of reporting success."""
+    import sys
+    from repro.launch import serve
+
+    def fail(self, *args, **kwargs):
+        raise RuntimeError("sweep failed")
+
+    monkeypatch.setattr(ChainPool, "_serve_group", fail)
+    monkeypatch.setattr(serve, "use_compile_cache", lambda: "")
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--workload", "hetero-pairs-24", "--chains", "4",
+        "--demo", "2"])
+    with pytest.raises(SystemExit) as exc:
+        serve.main()
+    assert exc.value.code != 0
+    assert "2 of 2 answers failed with status 'error'" in str(exc.value.code)
+    assert "statuses={'error': 2}" in capsys.readouterr().out
