@@ -39,27 +39,34 @@ SEED, SWEEP = 0, 16
 CHAINS, STEPS = 256, 200              # offline sampling on potts-20x20
 AGREE_CHAINS = 64                     # kernel-vs-oracle inputs
 DIST_CHAINS, DIST_STEPS = 64, 200     # the dist launcher on potts-64x64
-FOUR_CHAINS, FOUR_CALLS = 64, 50      # each dist engine, 1x1 and 1x4
+# each dist engine on 1x1 and 1x4.  Sized from one v5e host of four
+# (100 calls of 64 chains, compile included, 1x1 / 1x4): gibbs 9.5 / 7.7 s,
+# mgpmh 25.0 / 7.9 s, min-gibbs at lam 4096 411.9 / 121.1 s; min-gibbs's
+# time grows with lam, so at 512 its 30 calls take ~15 s on one device
+FOUR_CHAINS, FOUR_CALLS, FOUR_LAM = 64, 30, 512.0
 # Draw rates of the two global-minibatch samplers.  The paper recipe
 # (2 Psi^2 ~ 1.8e6 on potts-20x20, capped at 16384 by the engine) is far
-# past what a chain block holds in VMEM.  At this graph VMEM holds up to
-# 768 draw lanes (kernels.ops.sweep_vmem_bytes), but Mosaic's compile time
-# grows faster than the lanes (about 24 s at 256, 59 s at 384 for
-# min-gibbs), so these are the largest rates whose capacity is 256 lanes.
+# past what a chain block holds in VMEM; kernels.ops.sweep_vmem_bytes admits
+# up to lam 361 (min-gibbs, 512 lanes) and lam2 252 (doublemin, 384 lanes).
+# These rates (256 lanes each) compile for v5e in 38 s and 54 s of host
+# time against 156 s and 99 s at those limits, and the limits buy no
+# stronger check: on the CPU, doublemin accepts 0.28% of proposals at both
+# rates and min-gibbs falls less from START_ERR at 361 than at 147.
 MIN_GIBBS_LAM = 147.0
 DOUBLEMIN_LAM2 = 147.0
 # marg_err (mean over sites of the L2 distance of the marginal to uniform)
 # starts at START_ERR with every chain at x = 0 and falls as the chains mix.
-# CPU_MARG_ERR: the same launcher call on the CPU (jnp backend, which runs
-# the same chain with other random bits), same seed, chains and steps
-# (dist: DIST_CHAINS chains, DIST_STEPS steps on potts-64x64).  A chip run
-# passes when it covers at least half of the CPU run's fall.
+# CPU_REF: (marg_err, acceptance) of the same launcher call on the CPU (jnp
+# backend; dist: the dist backend on a 1x1 CPU mesh), same seed, chains and
+# steps (dist: DIST_CHAINS chains, DIST_STEPS steps on potts-64x64).  A chip
+# run passes when its marg_err lies within TOL of the CPU run's fall from
+# the CPU value, and its acceptance within TOL of the CPU acceptance: a
+# sampler that mixes too slowly or too fast fails, not only a stuck one.
 START_ERR = 0.9486833
-CPU_MARG_ERR = {"gibbs": 0.7461512, "mgpmh": 0.7705225,
-                "doublemin": 0.9464973, "min-gibbs": 0.9311761,
-                "dist": 0.9296890}
-MARG_ERR_BOUND = {k: START_ERR - 0.5 * (START_ERR - v)
-                  for k, v in CPU_MARG_ERR.items()}
+CPU_REF = {"gibbs": (0.7461512, 1.0), "mgpmh": (0.7705225, 0.9580505),
+           "doublemin": (0.9464973, 0.0027637),
+           "min-gibbs": (0.9311761, 1.0), "dist": (0.9296890, 0.9804639)}
+TOL = 0.25
 
 
 def say(phase: str, **fields) -> None:
@@ -70,6 +77,18 @@ def say(phase: str, **fields) -> None:
 def check(cond: bool, what: str) -> None:
     if not cond:
         raise AssertionError(what)
+
+
+def check_against_cpu(name: str, out: dict) -> None:
+    """The launcher's ``out`` agrees with the CPU run of ``CPU_REF``."""
+    import math
+    err, acc = out["marg_err"], out["acceptance"]
+    ref_err, ref_acc = CPU_REF[name]
+    check(math.isfinite(err)
+          and abs(err - ref_err) < TOL * (START_ERR - ref_err),
+          f"{name}: marg_err {err} too far from the CPU's {ref_err}")
+    check(abs(acc - ref_acc) <= TOL * ref_acc,
+          f"{name}: acceptance {acc} too far from the CPU's {ref_acc}")
 
 
 # ---------------------------------------------------------------------------
@@ -156,15 +175,14 @@ def phase_offline() -> None:
         desc = out["engine"]
         say("offline", engine=name, backend=desc["backend"],
             interpret=desc["interpret"], lam=params.get(name, {}),
-            marg_err=out["marg_err"], bound=MARG_ERR_BOUND[name],
-            acc=out["acceptance"], updates_per_sec=out["updates_per_sec"],
+            marg_err=out["marg_err"], cpu_marg_err=CPU_REF[name][0],
+            acc=out["acceptance"], cpu_acc=CPU_REF[name][1],
+            updates_per_sec=out["updates_per_sec"],
             seconds=time.time() - t0)
         check(desc["backend"] == "pallas" and desc["interpret"] is False,
               f"{name}: not the compiled pallas kernel: {desc}")
         check(bool(np.all(np.asarray(out["state"].x) >= 0)), "state")
-        check(np.isfinite(out["marg_err"])
-              and out["marg_err"] < MARG_ERR_BOUND[name],
-              f"{name}: marg_err {out['marg_err']} over its bound")
+        check_against_cpu(name, out)
         if name == "mgpmh":
             check(out["acceptance"] > 0.9,
                   f"mgpmh acceptance {out['acceptance']} <= 0.9")
@@ -181,6 +199,7 @@ def _chromatic_bitexact(mesh, C: int = 4, sweeps: int = 2) -> None:
     from repro.launch.mesh import make_device_mesh
     from repro.runtime.dist_gibbs import make_chromatic_gibbs_step
 
+    t0 = time.time()
     wl = engine.make_workload("lattice-ising-64x64")
     chromatic = engine.ChromaticBlocks(wl.colors)
     eng = engine.make("gibbs", wl.graph, backend="dist", mesh=mesh,
@@ -206,7 +225,7 @@ def _chromatic_bitexact(mesh, C: int = 4, sweeps: int = 2) -> None:
         model_shards=mesh.shape["model"], sweeps=sweeps,
         bitexact_vs_dense=bool(np.array_equal(x, np.asarray(x_ref))),
         chains_equal_vs_host=float(np.mean(np.all(x == x_host, axis=1))),
-        mean_spin=float(x.mean()))
+        mean_spin=float(x.mean()), seconds=time.time() - t0)
     check(np.array_equal(x, np.asarray(x_ref)),
           "chromatic dist gibbs differs from the dense reference")
     check(np.array_equal(x, x_host),
@@ -224,13 +243,11 @@ def phase_dist() -> None:
               mp_shards=1)
     say("dist", what="launcher-mgpmh-potts-64x64", mesh="1x1",
         backend=out["engine"]["backend"], marg_err=out["marg_err"],
-        bound=MARG_ERR_BOUND["dist"],
-        acc=out["acceptance"], updates_per_sec=out["updates_per_sec"],
+        cpu_marg_err=CPU_REF["dist"][0], acc=out["acceptance"],
+        cpu_acc=CPU_REF["dist"][1], updates_per_sec=out["updates_per_sec"],
         seconds=time.time() - t0)
     check(out["engine"]["backend"] == "dist", "not the dist backend")
-    check(bool(np.isfinite(out["marg_err"]))
-          and out["marg_err"] < MARG_ERR_BOUND["dist"],
-          f"dist marg_err {out['marg_err']} over its bound")
+    check_against_cpu("dist", out)
     check(out["acceptance"] > 0.9, f"dist acceptance {out['acceptance']}")
     _chromatic_bitexact(make_device_mesh((1, 1), ("data", "model"),
                                          jax.devices()[:1]))
@@ -276,11 +293,11 @@ def phase_four_chip() -> None:
     mesh1 = make_device_mesh((1, 1), ("data", "model"), devs[:1])
     C, calls = FOUR_CHAINS, FOUR_CALLS
     # the global-minibatch rates are cut from the default 16384: on one
-    # device min-gibbs's draw buffers would take ~19 GB at these chains,
-    # and at lam 4096 its 100 calls already took 412 s on one v5e
-    params = {"min-gibbs": dict(lam=1024.0), "doublemin": dict(lam2=1024.0)}
+    # device min-gibbs's draw buffers would take ~19 GB at these chains
+    params = {"min-gibbs": dict(lam=FOUR_LAM),
+              "doublemin": dict(lam2=FOUR_LAM)}
     for name in ("gibbs", "mgpmh", "min-gibbs", "doublemin"):
-        marg = {}
+        marg, t_engine = {}, time.time()
         for mesh in (mesh1, mesh4):
             eng = engine.make(name, g, sweep=SWEEP, backend="dist",
                               mesh=mesh, **params.get(name, {}))
@@ -308,7 +325,8 @@ def phase_four_chip() -> None:
             bitexact=bool(np.array_equal(m4, m1)),
             mean_abs_marg_diff=float(np.abs(m4 - m1).mean()),
             max_site_mean_diff=site_mean, marg_err_1x1=err[0],
-            marg_err_1x4=err[1], seconds_1x1=t1, seconds_1x4=t4)
+            marg_err_1x4=err[1], seconds_1x1=t1, seconds_1x4=t4,
+            seconds_total=time.time() - t_engine)
         check(bool(np.isfinite(m4).all()) and site_mean < 0.02
               and abs(err[1] - err[0]) < 0.05,
               f"{name}: 1x4 marginals differ from 1x1")

@@ -703,16 +703,9 @@ def _dist_engine(name: str, graph: MatchGraph, schedule: Schedule, mesh,
     # at scale; Engine.sweep documents the rebind-don't-reuse contract
     jstep = jax.jit(smapped, donate_argnums=(0,))
 
-    # XLA:CPU's in-process collectives deadlock when several of these
-    # sharded programs are in flight at once (a device's thread waits in
-    # one program's all-reduce while a peer is still in another's), so a
-    # CPU mesh runs one sweep at a time; a TPU mesh dispatches ahead
-    in_order = mesh.devices.flat[0].platform == "cpu"
-
     def sweep_fn(state):
         with mesh:
-            out = jstep(state, sh)
-        return jax.block_until_ready(out) if in_order else out
+            return jstep(state, sh)
 
     def init_fn(key: jax.Array, n_chains: int, *, start: str = "constant"):
         if start != "constant":

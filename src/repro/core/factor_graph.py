@@ -63,11 +63,14 @@ def build_alias_table(p: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     if total <= 0:
         # Degenerate: uniform table.
         return np.ones(m, np.float32), np.arange(m, dtype=np.int32)
-    q = p * (m / total)
-    prob = np.zeros(m, np.float64)
-    alias = np.zeros(m, np.int32)
-    small = [i for i in range(m) if q[i] < 1.0]
-    large = [i for i in range(m) if q[i] >= 1.0]
+    q_arr = p * (m / total)
+    # the pairing loop runs on python floats and lists: numpy scalar
+    # indexing made it the set-up cost of every large dense graph
+    q = q_arr.tolist()
+    prob = [0.0] * m
+    alias = [0] * m
+    small = np.flatnonzero(q_arr < 1.0).tolist()
+    large = np.flatnonzero(q_arr >= 1.0).tolist()
     while small and large:
         s = small.pop()
         l = large.pop()
@@ -79,7 +82,7 @@ def build_alias_table(p: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         prob[i] = 1.0
     for i in small:
         prob[i] = 1.0
-    return prob.astype(np.float32), alias.astype(np.int32)
+    return np.asarray(prob, np.float32), np.asarray(alias, np.int32)
 
 
 def alias_draw(key: jax.Array, prob: jax.Array, alias: jax.Array,

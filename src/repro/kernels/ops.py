@@ -135,8 +135,8 @@ def sweep_vmem_bytes(kernel: str, *, n: int, D: int, S: int, K: int = 0,
     for the (BC, Np, Dp) temporaries of the proposal kernels, 150 bytes per
     local alias draw lane and 256 per two-stage pair draw lane (the
     f32-exact one-hot gathers split their operands).  The constants bound
-    what Mosaic allocated for v5e at Np in {512, 1024} and draw widths in
-    {128, 256} (CHANGES.md).
+    what Mosaic allocates for v5e at potts-20x20 shapes and at the largest
+    shape each kernel is admitted at (``tests/test_tpu_compile.py``).
 
     ``kernel`` is 'gibbs' | 'mgpmh' | 'min-gibbs' | 'doublemin'; ``K`` is
     the draw capacity (doublemin: the proposal's, ``K2`` the second

@@ -3,6 +3,14 @@
 These spawn subprocesses because the 8-device host platform flag must be
 set before jax initializes — the main test process keeps 1 device (per the
 dry-run-only rule for device-count overrides).
+
+XLA:CPU's in-process collectives can hang when consecutive sharded programs
+overlap on a forced multi-device host: a jitted ``shard_map`` psum over a
+(2, 4) CPU mesh called 2000 times in a loop hangs in the all-reduce
+rendezvous in about half the runs, with async dispatch on or off, and
+never when each call is waited for.  ``_run`` therefore makes every
+``Engine.sweep`` wait for its result in these subprocesses (``_SERIAL``);
+the engine itself dispatches ahead on every platform.
 """
 import os
 import subprocess
@@ -14,11 +22,21 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+_SERIAL = """
+import jax
+from repro.core import engine as _engine
+_dispatch = _engine.Engine.sweep
+_engine.Engine.sweep = (
+    lambda self, *a, **k: jax.block_until_ready(_dispatch(self, *a, **k)))
+"""
+
+
 def _run(code: str) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+    out = subprocess.run([sys.executable, "-c",
+                          _SERIAL + textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
                          timeout=1200)
     assert out.returncode == 0, out.stderr[-3000:]

@@ -19,7 +19,7 @@ from repro.kernels import ops
 # S=16 sub-steps (Sp=128 lanes); draw widths: mgpmh's capacity 201 -> 256,
 # the global minibatches at 128 lanes
 N, D, S, C = 400, 10, 16, 64
-NP, DP, SP, S8 = 512, 128, 128, 16
+DP, SP, S8 = 128, 128, 16
 KP, K2P = 256, 128
 
 
@@ -43,15 +43,18 @@ def topo():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _kernel_call(name, sds):
+def _kernel_call(name, sds, n=N, kp=KP, k2p=K2P):
+    """(kernel, args, kwargs, (vmem-model kernel, draw dims)) at ``n``
+    sites, ``kp`` proposal and ``k2p`` global-minibatch draw lanes."""
     f32, i32 = jnp.float32, jnp.int32
+    NP, KP, K2P = ops._sweep_pads(C, n, S, D)[1], kp, k2p
     x, tab, tabi = sds((C, NP), i32), sds((NP, NP), f32), sds((NP, NP), i32)
     node, nodei = sds((8, NP), f32), sds((8, NP), i32)
     lane, lanef = sds((C, SP), i32), sds((C, SP), f32)
     gum, cache, seed = sds((C, S8, DP), f32), sds((C, DP), f32), sds((1,), i32)
     strm = lambda L: sds((C, S8, L), f32)
     cand = sds((C, S * D, K2P), f32)
-    common = dict(n=N, D=D, S=S)
+    common = dict(n=n, D=D, S=S)
     calls = {
         "gibbs": (F.gibbs_sweep_pallas, (x, tab, lane, gum),
                   dict(interpret=False), ("gibbs", {})),
@@ -89,6 +92,26 @@ def _kernel_call(name, sds):
     return fn, args, {**common, **kw}, model
 
 
+def _estimate(name, **shape):
+    """The engine's VMEM estimate for kernel ``name`` at ``shape``."""
+    _, _, kw, (kernel, dims) = _kernel_call(name, lambda *a: None, **shape)
+    return ops.sweep_vmem_bytes(kernel, n=kw["n"], D=D, S=S, **dims)
+
+
+def _compile_for_v5e(topo, name, **shape):
+    """Compile kernel ``name`` at ``shape`` for one v5e chip, as a Mosaic
+    custom call; returns the scoped VMEM Mosaic allocated."""
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    fn, args, kw, _ = _kernel_call(name, sds, **shape)
+    text = fn.lower(*args, **kw).compile().as_text()
+    assert "tpu_custom_call" in text
+    return int(re.search(r'"used_scoped_memory_configs":\[\{[^}]*'
+                         r'"size":"(\d+)"', text).group(1))
+
+
 @pytest.mark.parametrize("name", [
     "gibbs", "mgpmh", "mgpmh_rng", "min_gibbs", "min_gibbs_rng",
     "double_min", "double_min_rng"])
@@ -96,16 +119,23 @@ def test_fused_kernel_compiles_for_v5e(topo, name):
     """Each host-rng kernel and each in-kernel-PRNG variant compiles for
     one v5e chip at potts-20x20 shapes, as a Mosaic custom call, within
     the VMEM the engine's estimate grants it."""
-    from jax.sharding import SingleDeviceSharding
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
-                                                 sharding=one_chip)
-    fn, args, kw, (kernel, dims) = _kernel_call(name, sds)
-    text = fn.lower(*args, **kw).compile().as_text()
-    assert "tpu_custom_call" in text
-    used = int(re.search(r'"used_scoped_memory_configs":\[\{[^}]*'
-                         r'"size":"(\d+)"', text).group(1))
-    assert 0 < used <= ops.sweep_vmem_bytes(kernel, n=N, D=D, S=S, **dims)
+    assert 0 < _compile_for_v5e(topo, name) <= _estimate(name)
+
+
+# the largest shape along one axis that the estimate admits under the
+# limit: gibbs at Np 2432; at potts-20x20, mgpmh at 1024 proposal lanes,
+# min-gibbs at 512 pair lanes (lam 361) and doublemin at 384 second-batch
+# lanes (lam2 252).  One more 128-step along that axis is refused.
+@pytest.mark.parametrize("name,axis,size", [
+    ("gibbs", "n", 2432), ("mgpmh", "kp", 1024),
+    ("min_gibbs", "k2p", 512), ("double_min", "k2p", 384)])
+def test_fused_kernel_compiles_at_its_vmem_limit(topo, name, axis, size):
+    """At the largest shape ``engine.make`` lets through, each host-rng
+    kernel compiles for v5e, and Mosaic allocates no more VMEM than the
+    estimate the engine's refusal rests on."""
+    fits = _estimate(name, **{axis: size})
+    assert fits <= F.VMEM_LIMIT_BYTES < _estimate(name, **{axis: size + 128})
+    assert 0 < _compile_for_v5e(topo, name, **{axis: size}) <= fits
 
 
 def test_pallas_engine_beyond_vmem_raises_clear_error(monkeypatch):
