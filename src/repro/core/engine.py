@@ -58,6 +58,7 @@ from .estimators import (recommended_capacity, draw_global_minibatch,
                          min_gibbs_estimate)
 from . import samplers as S
 from ..kernels.ops import KernelTooLarge
+from ..obs import get_recorder
 
 __all__ = [
     "Engine", "Schedule", "UniformSites", "ChromaticBlocks", "AdaptiveScan",
@@ -255,8 +256,9 @@ class Engine:
                 f"clamping; serve conditioned queries from a jnp/pallas "
                 f"gibbs-family engine")
         kw = {} if evidence is None else {"evidence": evidence}
-        from ..obs import annotate
-        with annotate(f"repro.sweep/{self.name}/{self.backend}"):
+        # the whole call's host time; the fused-Pallas builders open
+        # ``repro.sweep.draws`` and ``repro.sweep.kernel`` inside it
+        with get_recorder().span("repro.sweep"):
             if telemetry is None:
                 return self.sweep_fn(state, **kw)
             from ..diagnostics.telemetry import telemetry_update
@@ -273,11 +275,10 @@ class Engine:
             # health hooks: the state's cached energy + the site domain feed
             # the in-graph guards (bad_state flag, windowed acceptance)
             # riding the telemetry carry — no host sync on this path
-            with annotate("repro.sweep/telemetry"):
-                telemetry = telemetry_update(
-                    telemetry, old_x, new.x, self.updates_per_call, delta,
-                    stats, cache=getattr(new, "cache", None),
-                    n_values=self.graph.D)
+            telemetry = telemetry_update(
+                telemetry, old_x, new.x, self.updates_per_call, delta,
+                stats, cache=getattr(new, "cache", None),
+                n_values=self.graph.D)
             return new, telemetry
 
     def clamp(self, key: jax.Array, state, evidence):

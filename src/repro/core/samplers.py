@@ -36,6 +36,7 @@ deprecation shims over ``engine.make`` and will be removed.
 from __future__ import annotations
 
 import warnings
+from contextlib import nullcontext
 from typing import NamedTuple
 
 import jax
@@ -46,6 +47,7 @@ from .factor_graph import MatchGraph, alias_draw, build_alias_table
 from .estimators import (draw_global_minibatch, draw_local_minibatch,
                          min_gibbs_estimate)
 from ..kernels import ops as kernel_ops
+from ..obs import get_recorder
 # telemetry.py is pure jnp (no repro.core imports); the diagnostics package
 # __init__ loads only it eagerly, so this import cannot cycle back here
 from ..diagnostics.telemetry import SweepStats
@@ -299,6 +301,14 @@ def _alias_gather(prob, alias, key, shape, m):
     return jnp.where(u < prob[idx], idx, alias[idx])
 
 
+def _span(name: str, impl: str = "pallas"):
+    """A host span around one phase of a fused-Pallas sweep:
+    ``repro.sweep.draws`` (key splits and every random draw, dispatched
+    eagerly before the kernel) or ``repro.sweep.kernel`` (the jitted
+    kernel's dispatch).  None on jnp."""
+    return get_recorder().span(name) if impl == "pallas" else nullcontext()
+
+
 def _check_impl(impl: str):
     if impl not in ("jnp", "pallas"):
         raise ValueError(f"impl must be 'jnp' or 'pallas' (engine.make owns "
@@ -418,13 +428,15 @@ def _build_gibbs_sweep(graph: MatchGraph, sweep_len: int, *,
         kernel_ops.check_sweep_vmem("gibbs", n=n, D=D, S=sweep_len)
 
     def sweep(state: ChainState, sites=None, evidence=None):
-        ki, kg, knew = _batch_keys(state.key, 3)
-        i = _draw_sites(ki, state.x.shape[0], sweep_len, n, sites, evidence,
-                        per_chain=True)                        # (C, S)
-        gumbel = jax.vmap(lambda k: jax.random.gumbel(
-            k, (sweep_len, D)))(kg)                            # (C, S, D)
-        x = kernel_ops.gibbs_sweep(state.x, graph.W, i, gumbel, D=D,
-                                   impl=impl)
+        with _span("repro.sweep.draws", impl):
+            ki, kg, knew = _batch_keys(state.key, 3)
+            i = _draw_sites(ki, state.x.shape[0], sweep_len, n, sites,
+                            evidence, per_chain=True)          # (C, S)
+            gumbel = jax.vmap(lambda k: jax.random.gumbel(
+                k, (sweep_len, D)))(kg)                        # (C, S, D)
+        with _span("repro.sweep.kernel", impl):
+            x = kernel_ops.gibbs_sweep(state.x, graph.W, i, gumbel, D=D,
+                                       impl=impl)
         new = state._replace(x=x, key=knew)
         if not collect_stats:
             return new
@@ -465,24 +477,26 @@ def _build_mgpmh_sweep(graph: MatchGraph, lam: float, capacity: int,
     kernel_ops.check_sweep_vmem("mgpmh", n=n, D=D, S=sweep_len, K=capacity)
 
     def sweep(state: ChainState, sites=None, evidence=None):
-        ki, kb, k1, k2, kg, ka, knew = _batch_keys(state.key, 7)
-        i = _draw_sites(ki, state.x.shape[0], sweep_len, n, sites, evidence,
-                        per_chain=True)                        # (C, S)
-        lam_i = lam * graph.row_sum[i] / graph.L               # (C, S)
-        B = jax.vmap(lambda k, l: jax.random.poisson(
-            k, l, dtype=jnp.int32))(kb, lam_i)
-        B = jnp.minimum(B, capacity)
-        u_idx = jax.vmap(lambda k: jax.random.uniform(
-            k, (sweep_len, capacity)))(k1)
-        u_alias = jax.vmap(lambda k: jax.random.uniform(
-            k, (sweep_len, capacity)))(k2)
-        gumbel = jax.vmap(lambda k: jax.random.gumbel(
-            k, (sweep_len, D)))(kg)
-        logu = jnp.log(jax.vmap(lambda k: jax.random.uniform(
-            k, (sweep_len,)))(ka))
-        x, acc = kernel_ops.mgpmh_sweep(
-            state.x, graph.W, graph.row_prob, graph.row_alias, i, B,
-            u_idx, u_alias, gumbel, logu, D=D, scale=scale, impl=impl)
+        with _span("repro.sweep.draws"):
+            ki, kb, k1, k2, kg, ka, knew = _batch_keys(state.key, 7)
+            i = _draw_sites(ki, state.x.shape[0], sweep_len, n, sites,
+                            evidence, per_chain=True)          # (C, S)
+            lam_i = lam * graph.row_sum[i] / graph.L           # (C, S)
+            B = jax.vmap(lambda k, l: jax.random.poisson(
+                k, l, dtype=jnp.int32))(kb, lam_i)
+            B = jnp.minimum(B, capacity)
+            u_idx = jax.vmap(lambda k: jax.random.uniform(
+                k, (sweep_len, capacity)))(k1)
+            u_alias = jax.vmap(lambda k: jax.random.uniform(
+                k, (sweep_len, capacity)))(k2)
+            gumbel = jax.vmap(lambda k: jax.random.gumbel(
+                k, (sweep_len, D)))(kg)
+            logu = jnp.log(jax.vmap(lambda k: jax.random.uniform(
+                k, (sweep_len,)))(ka))
+        with _span("repro.sweep.kernel"):
+            x, acc = kernel_ops.mgpmh_sweep(
+                state.x, graph.W, graph.row_prob, graph.row_alias, i, B,
+                u_idx, u_alias, gumbel, logu, D=D, scale=scale, impl=impl)
         new = state._replace(x=x, key=knew, accepts=state.accepts + acc)
         if not collect_stats:
             return new
@@ -679,18 +693,20 @@ def _build_min_gibbs_sweep_pallas(graph: MatchGraph, lam: float,
     node_prob, node_alias = _node_alias_table(graph)
 
     def sweep(state: ChainState, sites=None, evidence=None):
-        ki, kb, k1, k2, k3, k4, kg, knew = _batch_keys(state.key, 8)
-        i = _draw_sites(ki, state.x.shape[0], S, n, sites, evidence,
-                        per_chain=True)                    # (C, S)
-        B = jnp.minimum(jax.vmap(lambda k: jax.random.poisson(
-            k, lam, (S, D), dtype=jnp.int32))(kb), K)
-        draw = lambda ks: jax.vmap(lambda k: jax.random.uniform(
-            k, (S, D, K)))(ks)
-        gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (S, D)))(kg)
-        x, cache = kernel_ops.min_gibbs_sweep(
-            state.x, node_prob, node_alias, graph.row_prob, graph.row_alias,
-            i, B, draw(k1), draw(k2), draw(k3), draw(k4), gumbel,
-            state.cache, D=D, lscale=lscale, impl="pallas")
+        with _span("repro.sweep.draws"):
+            ki, kb, k1, k2, k3, k4, kg, knew = _batch_keys(state.key, 8)
+            i = _draw_sites(ki, state.x.shape[0], S, n, sites, evidence,
+                            per_chain=True)                # (C, S)
+            B = jnp.minimum(jax.vmap(lambda k: jax.random.poisson(
+                k, lam, (S, D), dtype=jnp.int32))(kb), K)
+            u1, u2, u3, u4 = (jax.vmap(lambda k: jax.random.uniform(
+                k, (S, D, K)))(ks) for ks in (k1, k2, k3, k4))
+            gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (S, D)))(kg)
+        with _span("repro.sweep.kernel"):
+            x, cache = kernel_ops.min_gibbs_sweep(
+                state.x, node_prob, node_alias, graph.row_prob,
+                graph.row_alias, i, B, u1, u2, u3, u4, gumbel, state.cache,
+                D=D, lscale=lscale, impl="pallas")
         new = state._replace(x=x, cache=cache, key=knew)
         if not collect_stats:
             return new
@@ -816,27 +832,29 @@ def _build_double_min_sweep_pallas(graph: MatchGraph, lam1: float,
     node_prob, node_alias = _node_alias_table(graph)
 
     def sweep(state: ChainState, sites=None, evidence=None):
-        (ki, kb1, k1, k2, kg, kb2, k3, k4, k5, k6, ka,
-         knew) = _batch_keys(state.key, 12)
-        i = _draw_sites(ki, state.x.shape[0], S, n, sites, evidence,
-                        per_chain=True)                    # (C, S)
-        lam_i = lam1 * graph.row_sum[i] / graph.L          # (C, S)
-        B1 = jnp.minimum(jax.vmap(lambda k, l: jax.random.poisson(
-            k, l, dtype=jnp.int32))(kb1, lam_i), K1)
-        u_idx = jax.vmap(lambda k: jax.random.uniform(k, (S, K1)))(k1)
-        u_alias = jax.vmap(lambda k: jax.random.uniform(k, (S, K1)))(k2)
-        gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (S, D)))(kg)
-        B2 = jnp.minimum(jax.vmap(lambda k: jax.random.poisson(
-            k, lam2, (S,), dtype=jnp.int32))(kb2), K2)
-        draw2 = lambda ks: jax.vmap(lambda k: jax.random.uniform(
-            k, (S, K2)))(ks)
-        logu = jnp.log(jax.vmap(lambda k: jax.random.uniform(
-            k, (S,)))(ka))
-        x, cache, acc = kernel_ops.double_min_sweep(
-            state.x, graph.row_prob, graph.row_alias, node_prob, node_alias,
-            i, B1, u_idx, u_alias, gumbel, B2, draw2(k3), draw2(k4),
-            draw2(k5), draw2(k6), logu, state.cache, D=D, scale1=scale1,
-            lscale2=lscale2, impl="pallas")
+        with _span("repro.sweep.draws"):
+            (ki, kb1, k1, k2, kg, kb2, k3, k4, k5, k6, ka,
+             knew) = _batch_keys(state.key, 12)
+            i = _draw_sites(ki, state.x.shape[0], S, n, sites, evidence,
+                            per_chain=True)                # (C, S)
+            lam_i = lam1 * graph.row_sum[i] / graph.L      # (C, S)
+            B1 = jnp.minimum(jax.vmap(lambda k, l: jax.random.poisson(
+                k, l, dtype=jnp.int32))(kb1, lam_i), K1)
+            u_idx = jax.vmap(lambda k: jax.random.uniform(k, (S, K1)))(k1)
+            u_alias = jax.vmap(lambda k: jax.random.uniform(k, (S, K1)))(k2)
+            gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (S, D)))(kg)
+            B2 = jnp.minimum(jax.vmap(lambda k: jax.random.poisson(
+                k, lam2, (S,), dtype=jnp.int32))(kb2), K2)
+            v1, v2, v3, v4 = (jax.vmap(lambda k: jax.random.uniform(
+                k, (S, K2)))(ks) for ks in (k3, k4, k5, k6))
+            logu = jnp.log(jax.vmap(lambda k: jax.random.uniform(
+                k, (S,)))(ka))
+        with _span("repro.sweep.kernel"):
+            x, cache, acc = kernel_ops.double_min_sweep(
+                state.x, graph.row_prob, graph.row_alias, node_prob,
+                node_alias, i, B1, u_idx, u_alias, gumbel, B2, v1, v2, v3,
+                v4, logu, state.cache, D=D, scale1=scale1, lscale2=lscale2,
+                impl="pallas")
         new = state._replace(x=x, cache=cache, key=knew,
                              accepts=state.accepts + acc)
         if not collect_stats:

@@ -3,16 +3,16 @@
 ``Recorder`` composes a :class:`~repro.obs.trace.TraceBuffer` and a
 :class:`~repro.obs.metrics.MetricsRegistry` and is what the engine,
 runtime, serving, and benchmark layers talk to.  ``NullRecorder`` is the
-default and is *total* no-op — every method returns immediately, spans
-are ``nullcontext`` — so an uninstrumented run pays nothing and, by the
-overhead tests, the instrumented fused jnp sweep path pays no host sync
-and ≤5% wall clock.
+default: every method returns immediately but ``span``, which opens a
+``jax.profiler.TraceAnnotation`` of the span's name and nothing else.  So
+every program span lands in a profiler capture on the device trace's
+clock whichever recorder is active, an uninstrumented run pays about what
+a ``nullcontext`` costs, and, by the overhead tests, the instrumented
+sweep path pays no host sync and ≤5% wall clock.
 
 Module-level plumbing (``get_recorder``/``set_recorder``/``using``/
 ``configure``) keeps call sites one import away from the active
-recorder without threading it through every signature; ``annotate``
-returns a ``jax.named_scope`` regardless of recorder, because trace-time
-name annotation costs nothing at runtime.
+recorder without threading it through every signature.
 """
 from __future__ import annotations
 
@@ -23,31 +23,25 @@ import time
 from contextlib import contextmanager, nullcontext
 from typing import Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
+from .compiles import jax_compile_counts
 from .metrics import MetricsRegistry
 from .trace import TraceBuffer
 
 __all__ = ["Recorder", "NullRecorder", "get_recorder", "set_recorder",
-           "using", "configure", "annotate"]
-
-
-def annotate(name: str):
-    """A ``jax.named_scope`` for device-side phase attribution.
-
-    Trace-time only: named scopes rename HLO ops during tracing and add
-    zero runtime work, so this is safe inside the sweep hot path even
-    with the null recorder active.
-    """
-    import jax
-    return jax.named_scope(name)
+           "using", "configure"]
 
 
 class NullRecorder:
-    """All-no-op recorder; the default when observability is off."""
+    """The default when observability is off: no files, no metrics; its
+    spans reach only a running profiler capture."""
 
     enabled = False
 
     def span(self, name: str, **args):
-        return nullcontext()
+        """A host span in any profiler capture (a near no-op without one)."""
+        return TraceAnnotation(name)
 
     def complete(self, name, ts_us, dur_us, **args):
         pass
@@ -98,6 +92,19 @@ class Recorder(NullRecorder):
     """
 
     enabled = True
+    # the process's JAX counts (``jax_compile_counts`` keys), exported by
+    # ``snapshot`` as counters
+    _COMPILE_SERIES = {
+        "traces": ("jax_traces_total", "JAX traces"),
+        "traces_s": ("jax_traces_seconds_total", "seconds in JAX traces"),
+        "lowerings": ("jax_lowerings_total", "JAX lowerings to MLIR"),
+        "lowerings_s": ("jax_lowerings_seconds_total",
+                        "seconds in JAX lowerings to MLIR"),
+        "compiles": ("jax_compiles_total",
+                     "XLA compiles, persistent-cache loads included"),
+        "compiles_s": ("jax_compiles_seconds_total",
+                       "seconds in XLA compiles and cache loads"),
+    }
 
     def __init__(self, metrics_dir: Optional[str] = None,
                  trace_path: Optional[str] = None,
@@ -109,16 +116,19 @@ class Recorder(NullRecorder):
         self.trace_path = trace_path
         self.profile_dir = profile_dir
         self._io_lock = threading.Lock()
+        self._compiles_seen = dict.fromkeys(self._COMPILE_SERIES, 0.0)
         if metrics_dir:
             os.makedirs(metrics_dir, exist_ok=True)
 
     # -- tracing ----------------------------------------------------------
     @contextmanager
     def span(self, name: str, **args):
-        """Host-side span: trace event + seconds/calls accumulators."""
+        """Host-side span: trace event + seconds/calls accumulators, and
+        the profiler's annotation as on the null recorder."""
         t0 = self.trace.now_us()
         try:
-            yield
+            with TraceAnnotation(name):
+                yield
         finally:
             dur = self.trace.now_us() - t0
             self.trace.complete(name, t0, dur, **args)
@@ -191,10 +201,19 @@ class Recorder(NullRecorder):
         return labels
 
     # -- export -----------------------------------------------------------
+    def _count_compiles(self):
+        """Bring the ``jax_*_total`` counters up to the process's counts."""
+        now = jax_compile_counts()
+        for key, (series, help_) in self._COMPILE_SERIES.items():
+            self.metrics.count(series, now[key] - self._compiles_seen[key],
+                               help=help_)
+        self._compiles_seen = now
+
     def snapshot(self):
         """Flush current metric values to disk (JSONL append + .prom
         rewrite) and refresh the trace file.  Called only at existing
         host-sync boundaries — never from inside the sweep path."""
+        self._count_compiles()
         if self.metrics_dir:
             series = self.metrics.snapshot()
             with self._io_lock:

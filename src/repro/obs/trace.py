@@ -10,10 +10,11 @@ its own track.
 
 Spans here are *host-side* wall-clock brackets around already-synced
 work (a dispatched chunk plus the health read that retires it, a
-checkpoint write, one serving query).  Device-side phase attribution is
-a different mechanism entirely — ``jax.named_scope`` in the sweep
-builders plus the opt-in ``jax.profiler.trace`` capture — precisely so
-that tracing never forces a host sync the hot path didn't already have.
+checkpoint write, one serving query), on this buffer's own clock.  The
+same spans reach a ``jax.profiler`` capture, on the device trace's clock,
+through the ``TraceAnnotation`` every recorder span opens
+(``recorder.py``); neither forces a host sync the hot path didn't
+already have.
 """
 from __future__ import annotations
 

@@ -1,9 +1,12 @@
 """Observability layer (repro/obs): metrics registry semantics, Chrome
 trace-event output, Prometheus exposition, recorder wiring through the
-supervised runtime / checkpoint / serving layers, and the overhead
+supervised runtime / checkpoint / serving layers, the sweep's spans in a
+profiler capture, the JAX trace/compile counter, and the overhead
 contracts — the null recorder adds zero host syncs to the fused sweep
 path and the instrumented path stays within the 5% wall-clock budget."""
+import glob
 import json
+import os
 import re
 import time
 
@@ -14,7 +17,8 @@ import pytest
 
 from repro.core import engine as engine_lib
 from repro.obs import (MetricsRegistry, NullRecorder, Recorder, TraceBuffer,
-                       configure, get_recorder, set_recorder, using)
+                       configure, get_recorder, jax_compile_counts,
+                       set_recorder, using)
 from repro.runtime.faultinject import Fault, FaultPlan
 from repro.runtime.supervisor import SupervisedRun, SupervisorConfig
 
@@ -191,28 +195,30 @@ def test_instrumentation_adds_no_device_ops():
     assert len(null_jaxpr.eqns) == len(live_jaxpr.eqns)
 
 
+SWEEP_SPANS = ("repro.sweep", "repro.sweep.draws", "repro.sweep.kernel")
+
+
 def test_instrumented_sweep_within_overhead_budget():
-    """min-of-N wall clock of a spanned sweep block stays within the 5%
-    budget of the bare block (plus a 1ms absolute floor for timer noise)."""
+    """The spans one sweep call opens (``repro.sweep`` and, on the fused
+    Pallas path, its draws and kernel children) cost under 5% of the
+    least wall time of one warm sweep call, under the null recorder (no
+    profiler running) and under an active Recorder.  The spans are timed
+    alone over many iterations: two blocks of whole sweeps differ by less
+    than the noise of a shared host."""
     eng, st0 = _warm_engine(sweep=24, chains=8)
-    rec = Recorder()
-    labels = rec.register_engine(eng, workload="hetero-pairs-24", chains=8)
-    calls = 16
 
-    def bare():
-        st = st0
-        for _ in range(calls):
-            st = eng.sweep(st)
-        jax.block_until_ready(st.x)
+    def one_call():
+        jax.block_until_ready(eng.sweep(st0).x)
 
-    def spanned():
-        st = st0
-        with rec.span("sweep_chunk", **labels):
-            for _ in range(calls):
-                st = eng.sweep(st)
-            jax.block_until_ready(st.x)
+    def spans(rec):
+        for _ in range(200):
+            with rec.span(SWEEP_SPANS[0]):
+                with rec.span(SWEEP_SPANS[1]):
+                    pass
+                with rec.span(SWEEP_SPANS[2]):
+                    pass
 
-    def best_of(fn, n=7):
+    def best_of(fn, n):
         best = float("inf")
         for _ in range(n):
             t0 = time.perf_counter()
@@ -220,10 +226,95 @@ def test_instrumented_sweep_within_overhead_budget():
             best = min(best, time.perf_counter() - t0)
         return best
 
-    bare()
-    spanned()                       # warm both paths
-    t_bare, t_span = best_of(bare), best_of(spanned)
-    assert t_span <= max(1.05 * t_bare, t_bare + 1e-3), (t_bare, t_span)
+    t_call = best_of(one_call, 20)
+    for rec in (NullRecorder(), Recorder()):
+        t_spans = best_of(lambda: spans(rec), 7) / 200
+        assert t_spans <= 0.05 * t_call, (type(rec).__name__, t_spans,
+                                          t_call)
+
+
+def _host_spans(profile_dir):
+    """``(line, name, t0, t1)`` of every ``repro.*`` host event in the
+    profiler capture under ``profile_dir``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host"):
+            for k, line in enumerate(plane.lines):
+                out += [((plane.name, k), e.name, e.start_ns,
+                         e.start_ns + e.duration_ns) for e in line.events
+                        if e.name.startswith("repro.")]
+    return out
+
+
+def test_sweep_spans_reach_a_profiler_capture(tmp_path):
+    """Two fused-Pallas gibbs sweeps under a profiler capture, with no
+    Recorder configured: each call's ``repro.sweep`` span holds its
+    ``repro.sweep.draws`` span and then its ``repro.sweep.kernel`` span,
+    all on one host line."""
+    eng = engine_lib.make("gibbs", GRAPH, sweep=4, backend="pallas")
+    st = eng.init(jax.random.PRNGKey(0), 4)
+    st = jax.block_until_ready(eng.sweep(st))
+    assert not get_recorder().enabled
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            st = eng.sweep(st)
+        jax.block_until_ready(st)
+    finally:
+        jax.profiler.stop_trace()
+    spans = sorted(_host_spans(str(tmp_path)), key=lambda e: e[2])
+    assert [n for _, n, _, _ in spans] == list(SWEEP_SPANS) * 2
+    assert len({line for line, _, _, _ in spans}) == 1
+    for k in (0, 3):
+        (_, _, s0, s1), (_, _, d0, d1), (_, _, k0, k1) = spans[k:k + 3]
+        assert s0 <= d0 < d1 <= k0 < k1 <= s1
+
+
+def test_jax_compile_counts_count_one_trace_per_fresh_jit():
+    """A ``jax.jit`` of a fresh function traces, lowers and compiles once;
+    the cached call counts nothing.  An active Recorder exports the
+    process's counts at ``snapshot``.  (Primitives only: a ``jnp``
+    function is a jit of its own and adds traces of its own.)"""
+    x = jnp.arange(4.0)
+    f = jax.jit(lambda v: jax.lax.add(jax.lax.mul(v, v), v))
+    c0 = jax_compile_counts()
+    jax.block_until_ready(f(x))
+    c1 = jax_compile_counts()
+    jax.block_until_ready(f(x))
+    c2 = jax_compile_counts()
+    assert c1["traces"] - c0["traces"] == 1
+    assert c1["lowerings"] - c0["lowerings"] == 1
+    assert c1["compiles"] - c0["compiles"] == 1
+    assert c1["traces_s"] > c0["traces_s"]
+    assert c2 == c1
+    rec = Recorder()
+    rec.snapshot()
+    for kind in ("traces", "lowerings", "compiles"):
+        assert rec.metrics.value(f"jax_{kind}_total") == c2[kind]
+        assert rec.metrics.value(f"jax_{kind}_seconds_total") == \
+            pytest.approx(c2[kind + "_s"])
+    assert "# TYPE repro_jax_traces_total counter" in \
+        rec.metrics.to_prometheus()
+
+
+def test_recorder_span_reaches_a_profiler_capture(tmp_path):
+    """An active Recorder's span lands in its own trace and metrics and in
+    a running profiler capture."""
+    rec = Recorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("repro.test", step=1):
+            jnp.ones(3).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    assert [n for _, n, _, _ in _host_spans(str(tmp_path))] == \
+        ["repro.test"]
+    assert rec.metrics.value("span_calls_total", span="repro.test") == 1
+    assert [e["name"] for e in rec.trace.events() if e["ph"] == "X"] == \
+        ["repro.test"]
 
 
 # -- supervised runtime golden files -----------------------------------------
