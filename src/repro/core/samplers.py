@@ -303,9 +303,9 @@ def _alias_gather(prob, alias, key, shape, m):
 
 def _span(name: str, impl: str = "pallas"):
     """A host span around one phase of a fused-Pallas sweep:
-    ``repro.sweep.draws`` (key splits and every random draw, dispatched
-    eagerly before the kernel) or ``repro.sweep.kernel`` (the jitted
-    kernel's dispatch).  None on jnp."""
+    ``repro.sweep.draws`` (the dispatch of the builder's one jitted
+    program of key splits and random draws) or ``repro.sweep.kernel``
+    (the jitted kernel's dispatch).  None on jnp."""
     return get_recorder().span(name) if impl == "pallas" else nullcontext()
 
 
@@ -371,6 +371,11 @@ def min_gibbs_select(eps: jax.Array, cache: jax.Array, xi: jax.Array,
 #     unclamped calls share one jit trace.  The caller must have clamped
 #     ``state.x`` at the observed sites (``Engine.clamp``); the chromatic
 #     sweep instead re-clamps x between color classes.
+# The fused-Pallas builders jit their key splits and draws into one
+# program, built once per builder and dispatched before the kernel's own
+# program: one dispatch a call where eager draws cost dozens.  Graph
+# arrays are its arguments, not constants folded into it; a ``None`` or
+# an array for ``sites`` / ``evidence`` is one trace each.
 
 
 def evidence_cdf(ev_mask: jax.Array) -> jax.Array:
@@ -427,13 +432,20 @@ def _build_gibbs_sweep(graph: MatchGraph, sweep_len: int, *,
     if impl == "pallas":
         kernel_ops.check_sweep_vmem("gibbs", n=n, D=D, S=sweep_len)
 
+    def gibbs_draws(keys, sites, evidence):
+        ki, kg, knew = _batch_keys(keys, 3)
+        i = _draw_sites(ki, keys.shape[0], sweep_len, n, sites, evidence,
+                        per_chain=True)                        # (C, S)
+        gumbel = jax.vmap(lambda k: jax.random.gumbel(
+            k, (sweep_len, D)))(kg)                            # (C, S, D)
+        return i, gumbel, knew
+
+    # one program on the fused-Pallas path; the jnp schedule draws eagerly
+    draws = jax.jit(gibbs_draws) if impl == "pallas" else gibbs_draws
+
     def sweep(state: ChainState, sites=None, evidence=None):
         with _span("repro.sweep.draws", impl):
-            ki, kg, knew = _batch_keys(state.key, 3)
-            i = _draw_sites(ki, state.x.shape[0], sweep_len, n, sites,
-                            evidence, per_chain=True)          # (C, S)
-            gumbel = jax.vmap(lambda k: jax.random.gumbel(
-                k, (sweep_len, D)))(kg)                        # (C, S, D)
+            i, gumbel, knew = draws(state.key, sites, evidence)
         with _span("repro.sweep.kernel", impl):
             x = kernel_ops.gibbs_sweep(state.x, graph.W, i, gumbel, D=D,
                                        impl=impl)
@@ -476,23 +488,29 @@ def _build_mgpmh_sweep(graph: MatchGraph, lam: float, capacity: int,
     scale = float(graph.L / lam)
     kernel_ops.check_sweep_vmem("mgpmh", n=n, D=D, S=sweep_len, K=capacity)
 
+    @jax.jit
+    def mgpmh_draws(keys, row_sum, sites, evidence):
+        ki, kb, k1, k2, kg, ka, knew = _batch_keys(keys, 7)
+        i = _draw_sites(ki, keys.shape[0], sweep_len, n, sites, evidence,
+                        per_chain=True)                        # (C, S)
+        lam_i = lam * row_sum[i] / graph.L                     # (C, S)
+        B = jax.vmap(lambda k, l: jax.random.poisson(
+            k, l, dtype=jnp.int32))(kb, lam_i)
+        B = jnp.minimum(B, capacity)
+        u_idx = jax.vmap(lambda k: jax.random.uniform(
+            k, (sweep_len, capacity)))(k1)
+        u_alias = jax.vmap(lambda k: jax.random.uniform(
+            k, (sweep_len, capacity)))(k2)
+        gumbel = jax.vmap(lambda k: jax.random.gumbel(
+            k, (sweep_len, D)))(kg)
+        logu = jnp.log(jax.vmap(lambda k: jax.random.uniform(
+            k, (sweep_len,)))(ka))
+        return i, B, u_idx, u_alias, gumbel, logu, knew
+
     def sweep(state: ChainState, sites=None, evidence=None):
         with _span("repro.sweep.draws"):
-            ki, kb, k1, k2, kg, ka, knew = _batch_keys(state.key, 7)
-            i = _draw_sites(ki, state.x.shape[0], sweep_len, n, sites,
-                            evidence, per_chain=True)          # (C, S)
-            lam_i = lam * graph.row_sum[i] / graph.L           # (C, S)
-            B = jax.vmap(lambda k, l: jax.random.poisson(
-                k, l, dtype=jnp.int32))(kb, lam_i)
-            B = jnp.minimum(B, capacity)
-            u_idx = jax.vmap(lambda k: jax.random.uniform(
-                k, (sweep_len, capacity)))(k1)
-            u_alias = jax.vmap(lambda k: jax.random.uniform(
-                k, (sweep_len, capacity)))(k2)
-            gumbel = jax.vmap(lambda k: jax.random.gumbel(
-                k, (sweep_len, D)))(kg)
-            logu = jnp.log(jax.vmap(lambda k: jax.random.uniform(
-                k, (sweep_len,)))(ka))
+            i, B, u_idx, u_alias, gumbel, logu, knew = mgpmh_draws(
+                state.key, graph.row_sum, sites, evidence)
         with _span("repro.sweep.kernel"):
             x, acc = kernel_ops.mgpmh_sweep(
                 state.x, graph.W, graph.row_prob, graph.row_alias, i, B,
@@ -692,16 +710,22 @@ def _build_min_gibbs_sweep_pallas(graph: MatchGraph, lam: float,
     lscale = float(np.log1p(graph.psi / lam))
     node_prob, node_alias = _node_alias_table(graph)
 
+    @jax.jit
+    def min_gibbs_draws(keys, sites, evidence):
+        ki, kb, k1, k2, k3, k4, kg, knew = _batch_keys(keys, 8)
+        i = _draw_sites(ki, keys.shape[0], S, n, sites, evidence,
+                        per_chain=True)                    # (C, S)
+        B = jnp.minimum(jax.vmap(lambda k: jax.random.poisson(
+            k, lam, (S, D), dtype=jnp.int32))(kb), K)
+        u1, u2, u3, u4 = (jax.vmap(lambda k: jax.random.uniform(
+            k, (S, D, K)))(ks) for ks in (k1, k2, k3, k4))
+        gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (S, D)))(kg)
+        return i, B, u1, u2, u3, u4, gumbel, knew
+
     def sweep(state: ChainState, sites=None, evidence=None):
         with _span("repro.sweep.draws"):
-            ki, kb, k1, k2, k3, k4, kg, knew = _batch_keys(state.key, 8)
-            i = _draw_sites(ki, state.x.shape[0], S, n, sites, evidence,
-                            per_chain=True)                # (C, S)
-            B = jnp.minimum(jax.vmap(lambda k: jax.random.poisson(
-                k, lam, (S, D), dtype=jnp.int32))(kb), K)
-            u1, u2, u3, u4 = (jax.vmap(lambda k: jax.random.uniform(
-                k, (S, D, K)))(ks) for ks in (k1, k2, k3, k4))
-            gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (S, D)))(kg)
+            i, B, u1, u2, u3, u4, gumbel, knew = min_gibbs_draws(
+                state.key, sites, evidence)
         with _span("repro.sweep.kernel"):
             x, cache = kernel_ops.min_gibbs_sweep(
                 state.x, node_prob, node_alias, graph.row_prob,
@@ -831,24 +855,31 @@ def _build_double_min_sweep_pallas(graph: MatchGraph, lam1: float,
     lscale2 = float(np.log1p(graph.psi / lam2))
     node_prob, node_alias = _node_alias_table(graph)
 
+    @jax.jit
+    def double_min_draws(keys, row_sum, sites, evidence):
+        (ki, kb1, k1, k2, kg, kb2, k3, k4, k5, k6, ka,
+         knew) = _batch_keys(keys, 12)
+        i = _draw_sites(ki, keys.shape[0], S, n, sites, evidence,
+                        per_chain=True)                    # (C, S)
+        lam_i = lam1 * row_sum[i] / graph.L                # (C, S)
+        B1 = jnp.minimum(jax.vmap(lambda k, l: jax.random.poisson(
+            k, l, dtype=jnp.int32))(kb1, lam_i), K1)
+        u_idx = jax.vmap(lambda k: jax.random.uniform(k, (S, K1)))(k1)
+        u_alias = jax.vmap(lambda k: jax.random.uniform(k, (S, K1)))(k2)
+        gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (S, D)))(kg)
+        B2 = jnp.minimum(jax.vmap(lambda k: jax.random.poisson(
+            k, lam2, (S,), dtype=jnp.int32))(kb2), K2)
+        v1, v2, v3, v4 = (jax.vmap(lambda k: jax.random.uniform(
+            k, (S, K2)))(ks) for ks in (k3, k4, k5, k6))
+        logu = jnp.log(jax.vmap(lambda k: jax.random.uniform(
+            k, (S,)))(ka))
+        return i, B1, u_idx, u_alias, gumbel, B2, v1, v2, v3, v4, logu, knew
+
     def sweep(state: ChainState, sites=None, evidence=None):
         with _span("repro.sweep.draws"):
-            (ki, kb1, k1, k2, kg, kb2, k3, k4, k5, k6, ka,
-             knew) = _batch_keys(state.key, 12)
-            i = _draw_sites(ki, state.x.shape[0], S, n, sites, evidence,
-                            per_chain=True)                # (C, S)
-            lam_i = lam1 * graph.row_sum[i] / graph.L      # (C, S)
-            B1 = jnp.minimum(jax.vmap(lambda k, l: jax.random.poisson(
-                k, l, dtype=jnp.int32))(kb1, lam_i), K1)
-            u_idx = jax.vmap(lambda k: jax.random.uniform(k, (S, K1)))(k1)
-            u_alias = jax.vmap(lambda k: jax.random.uniform(k, (S, K1)))(k2)
-            gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (S, D)))(kg)
-            B2 = jnp.minimum(jax.vmap(lambda k: jax.random.poisson(
-                k, lam2, (S,), dtype=jnp.int32))(kb2), K2)
-            v1, v2, v3, v4 = (jax.vmap(lambda k: jax.random.uniform(
-                k, (S, K2)))(ks) for ks in (k3, k4, k5, k6))
-            logu = jnp.log(jax.vmap(lambda k: jax.random.uniform(
-                k, (S,)))(ka))
+            (i, B1, u_idx, u_alias, gumbel, B2, v1, v2, v3, v4, logu,
+             knew) = double_min_draws(state.key, graph.row_sum, sites,
+                                      evidence)
         with _span("repro.sweep.kernel"):
             x, cache, acc = kernel_ops.double_min_sweep(
                 state.x, graph.row_prob, graph.row_alias, node_prob,

@@ -1,6 +1,6 @@
 """Fused multi-site sweep engine validation.
 
-Four layers:
+Five layers:
   * kernel parity — the fused Pallas sweep kernels (interpret mode on CPU)
     must make bit-identical decisions to their jnp oracles when fed the
     same pre-drawn uniforms, across padded/unaligned (C, S, K, D, n)
@@ -11,10 +11,15 @@ Four layers:
   * memory regression — the jnp min-gibbs/doublemin sweeps draw their
     minibatch streams inside the scan body, so peak temp bytes (XLA
     memory_analysis) must not scale with sweep length S;
+  * draws — the fused-Pallas builders' draws are one jitted program: warm
+    calls trace nothing, and under evidence the streams are the ones the
+    benchmark's oracles replay;
   * integration — `run_marginal_experiment` consumes batched sweeps.  (The
     distributed sweeps — one psum per sweep for all four algorithms — are
     validated against exact marginals in tests/test_distributed.py.)
 """
+import contextlib
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -232,6 +237,85 @@ def test_jnp_sweep_peak_memory_independent_of_sweep_len(name, params):
         engine.make(name, g, sweep=S, backend="jnp", **params))
         for S in (4, 32)}
     assert temp[32] < 2.0 * temp[4], temp
+
+
+# ---------------------------------------------------------------------------
+# the fused-Pallas builders' draws: one jitted program, the streams kept
+# ---------------------------------------------------------------------------
+
+@contextlib.contextmanager
+def _traced_functions():
+    """Names of the functions JAX traces inside the block (nested jitted
+    helpers included, as ``jax_compile_counts()["traces"]`` counts them)."""
+    names = []
+
+    def listen(event, duration_secs, **kw):
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            names.append(kw.get("fun_name", ""))
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield names
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
+@pytest.mark.parametrize("evidence", [False, True])
+@pytest.mark.parametrize("name", ["gibbs", "mgpmh", "min-gibbs",
+                                  "doublemin"])
+def test_pallas_sweep_retraces_nothing_when_warm(name, evidence):
+    """Warm fused-Pallas sweeps trace nothing: the draws are one jitted
+    program built with the engine.  The first call with ``evidence=``
+    traces that program once more (with the jnp helpers inside it) and
+    the kernel's not at all; later ones trace nothing."""
+    from repro.obs import jax_compile_counts
+    g = make_potts_graph(grid=2, beta=0.8, D=3)
+    eng = engine.make(name, g, sweep=4, backend="pallas")
+    st = eng.init(jax.random.PRNGKey(0), 4)
+    ev = (jnp.zeros((g.n,), jnp.float32).at[1].set(1.0),
+          jnp.zeros((g.n,), jnp.int32))
+    kw = {"evidence": ev} if evidence else {}
+    if evidence:
+        st = eng.clamp(jax.random.PRNGKey(1), st, ev)
+    for _ in range(2):
+        st = eng.sweep(st)
+    if evidence:
+        with _traced_functions() as names:
+            st = eng.sweep(st, **kw)
+        programs = [f for f in names if f.endswith(("_draws", "_sweep"))]
+        assert len(programs) == 1 and programs[0].endswith("_draws"), names
+    before = jax_compile_counts()["traces"]
+    for _ in range(5):
+        st = eng.sweep(st, **kw)
+    jax.block_until_ready(st)
+    assert jax_compile_counts()["traces"] == before
+
+
+@pytest.mark.parametrize("name", ["gibbs", "mgpmh"])
+def test_pallas_sweep_with_evidence_replays_oracle(name):
+    """The pallas gibbs and mgpmh sweeps under evidence draw the streams
+    the benchmark's oracle replays (``bench/oracle/<engine>_pallas.py``):
+    sites, accept counts and keys equal bit for bit, call after call."""
+    from bench import check
+    cfg = {"reference": {"family": "potts", "grid": 6, "beta": 4.6,
+                         "D": 10, "gamma": 1.5}}
+    g = make_potts_graph(grid=6, beta=4.6, D=10, gamma=1.5)
+    eng = engine.make(name, g, sweep=4, backend="pallas")
+    call = jax.jit(check.oracle({"engine": name, "backend": "pallas",
+                                 "sweep": 4}, cfg, dict(eng.params),
+                                "highest"))
+    observed = jnp.array([0, 7, 20, 35])
+    ev = (jnp.zeros((g.n,), jnp.float32).at[observed].set(1.0),
+          jnp.zeros((g.n,), jnp.int32).at[observed].set(
+              jnp.array([3, 9, 0, 5])))
+    st = eng.clamp(jax.random.PRNGKey(1), eng.init(
+        jax.random.PRNGKey(7), 8, start="random"), ev)
+    for _ in range(3):
+        want = call({"x": st.x, "key": st.key, "accepts": st.accepts}, ev)
+        st = eng.sweep(st, evidence=ev)
+        for field in ("x", "key", "accepts"):
+            np.testing.assert_array_equal(np.asarray(getattr(st, field)),
+                                          np.asarray(want[field]))
+    assert np.all(np.asarray(st.x)[:, observed] == [3, 9, 0, 5])
 
 
 # ---------------------------------------------------------------------------
