@@ -51,9 +51,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
-from .factor_graph import (MatchGraph, make_ising_graph, make_potts_graph,
-                           make_lattice_ising, lattice_colors,
-                           make_pair_ising, pair_colors)
+from .factor_graph import (MatchGraph, NeighborGraph, make_ising_graph,
+                           make_potts_graph, make_lattice_ising,
+                           lattice_colors, make_pair_ising, pair_colors)
 from .estimators import (recommended_capacity, draw_global_minibatch,
                          min_gibbs_estimate)
 from . import samplers as S
@@ -186,7 +186,7 @@ class Engine:
     schedule: Schedule
     updates_per_call: int
     marginal_samples_per_call: int
-    graph: MatchGraph
+    graph: Any              # MatchGraph, or NeighborGraph (chromatic gibbs)
     params: Dict[str, Any] = dataclasses.field(repr=False)
     init_fn: Callable = dataclasses.field(repr=False)
     sweep_fn: Callable = dataclasses.field(repr=False)
@@ -356,7 +356,13 @@ def make(name: str, graph: MatchGraph, *, sweep: Optional[int] = None,
     :class:`Schedule` for anything else — :class:`ChromaticBlocks` (gibbs)
     or :class:`AdaptiveScan` (gibbs/mgpmh/min-gibbs/doublemin,
     telemetry-driven non-uniform site selection; state carries its own
-    diagnostics).  ``backend`` is
+    diagnostics).  ``schedule="chromatic"`` is
+    ``ChromaticBlocks(graph.colors)`` for a graph that carries its
+    colouring; ``sweep=`` is then accepted only as n, the updates one call
+    makes.  ``make`` dispatches on the graph's layout: a
+    :class:`~repro.core.factor_graph.NeighborGraph` runs gibbs under the
+    chromatic schedule on jnp, the parallel neighbour-list sweep; every
+    other engine, schedule or backend refuses it.  ``backend`` is
     'auto' | 'pallas' | 'jnp' | 'dist' ('dist' needs ``mesh=``).  Algorithm
     parameters (lam, capacity, ...) are keyword ``params`` with
     paper-recipe defaults.
@@ -364,12 +370,38 @@ def make(name: str, graph: MatchGraph, *, sweep: Optional[int] = None,
     if name not in _BUILDERS:
         raise KeyError(f"unknown engine {name!r}; available: {list(names())}")
     builder, supported = _BUILDERS[name]
+    if schedule == "chromatic":
+        colors = getattr(graph, "colors", None)
+        if colors is None:
+            raise ValueError("schedule='chromatic' needs a graph that "
+                             "carries its colouring (a NeighborGraph); pass "
+                             "schedule=ChromaticBlocks(colors) for a dense "
+                             "graph")
+        if sweep is not None and sweep != graph.n:
+            raise ValueError(f"schedule='chromatic' updates every site once "
+                             f"a call: sweep= must be n={graph.n} or left "
+                             f"out, got {sweep}")
+        schedule, sweep = ChromaticBlocks(colors), None
     if schedule is None:
         schedule = UniformSites(sweep if sweep is not None else 1)
     elif sweep is not None:
         raise ValueError("pass either sweep= or schedule=, not both")
     if not isinstance(schedule, Schedule):
-        raise TypeError(f"schedule must be a Schedule, got {schedule!r}")
+        raise TypeError(f"schedule must be a Schedule or 'chromatic', got "
+                        f"{schedule!r}")
+    if isinstance(graph, NeighborGraph):
+        if name != "gibbs":
+            raise ValueError(
+                f"engine {name!r} needs the dense MatchGraph layout; the "
+                f"neighbour-list layout runs gibbs only (minibatching buys "
+                f"nothing at bounded degree, Delta={graph.delta})")
+        if not isinstance(schedule, ChromaticBlocks):
+            raise ValueError(f"the neighbour-list layout runs the chromatic "
+                             f"schedule only, got {schedule.describe()}")
+        if backend not in ("auto", "jnp"):
+            raise ValueError(f"the neighbour-list layout runs on backend "
+                             f"'jnp' only, got {backend!r}")
+        backend = "jnp"
     if backend == "auto":
         if jax.default_backend() == "tpu" and "pallas" in supported:
             try:
@@ -442,6 +474,12 @@ def _reject_unknown(name, params):
 @register("gibbs", backends=("jnp", "pallas", "dist"))
 def _gibbs_builder(graph, *, schedule, backend, mesh, **params):
     _reject_unknown("gibbs", params)
+    if isinstance(graph, NeighborGraph):      # make() admitted it: jnp
+        build = lambda cs: S._build_neighbor_chromatic_sweep(
+            graph, collect_stats=cs)
+        return _engine("gibbs", backend, schedule, graph.n, graph, {},
+                       _chain_init(graph), build(False), stats_fn=build(True),
+                       exact_accept=True, supports_evidence=True)
     if backend == "dist":
         return _dist_engine("gibbs", graph, schedule, mesh, {})
     if isinstance(schedule, AdaptiveScan):

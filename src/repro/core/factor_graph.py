@@ -17,6 +17,10 @@ symmetric non-negative match-weight matrix W.  This file defines:
   energy ``L``, max degree ``Delta``) plus precomputed alias tables for O(1)
   categorical factor draws (the Poisson->multinomial trick of the paper's
   footnote 7).
+* :class:`NeighborGraph` — the same model as padded neighbour lists (ELL)
+  for sparse bounded-degree graphs (lattices), with its proper colouring:
+  O(n * Delta) bytes where the dense layout needs O(n^2).  The gibbs
+  engine's parallel chromatic sweep runs on it.
 * :class:`TabularPairwiseGraph` — general tabular pairwise factors used by
   the exact spectral-gap validators (small state spaces only).
 
@@ -32,8 +36,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import get_recorder
+
 __all__ = [
     "MatchGraph",
+    "NeighborGraph",
     "TabularPairwiseGraph",
     "build_alias_table",
     "alias_draw",
@@ -41,6 +48,7 @@ __all__ = [
     "make_ising_graph",
     "make_potts_graph",
     "make_lattice_ising",
+    "make_lattice_graph",
     "lattice_colors",
     "make_pair_ising",
     "pair_colors",
@@ -298,6 +306,141 @@ def make_pair_ising(n_strong: int, n_weak: int, w_strong: float = 3.5,
 def pair_colors(n_pairs: int) -> np.ndarray:
     """Proper 2-coloring of ``make_pair_ising`` (even/odd site of a pair)."""
     return (np.arange(2 * n_pairs) % 2).astype(np.int32)
+
+
+# ---------------------------------------------------------------------------
+# NeighborGraph — padded neighbour lists for sparse bounded-degree graphs
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class NeighborGraph:
+    """Sparse weighted-match pairwise graph as padded neighbour lists (ELL).
+
+    The same model as :class:`MatchGraph` (``phi_ij = W_ij d(x_i, x_j)``,
+    one factor per unordered pair) held in O(n * Delta) bytes: row ``i``
+    lists the neighbours of site ``i`` in ascending index order, then
+    padding.  A padding slot points at ``i`` itself with weight 0, so it
+    adds nothing to an energy; a slot is an edge exactly where its weight
+    is non-zero.  The graph carries its proper colouring (no edge joins
+    two sites of one colour), which ``schedule="chromatic"`` sweeps by.
+
+    Attributes
+    ----------
+    nbr    : (n, delta) int32 neighbour ids, ascending, padding last.
+    w      : (n, delta) float32 match weights W_ij, 0 in padding slots.
+    colors : (n,) int32 host array, a proper colouring with colours
+             0..k-1, each used.
+    D      : domain size of every variable.
+    delta  : max degree Delta (the row width).
+    """
+
+    nbr: jax.Array
+    w: jax.Array
+    colors: np.ndarray
+    D: int
+    delta: int
+
+    @property
+    def n(self) -> int:
+        return self.nbr.shape[0]
+
+    @property
+    def n_colors(self) -> int:
+        return int(self.colors.max()) + 1
+
+    @property
+    def table_bytes(self) -> int:
+        """Bytes of the device tables (``nbr`` and ``w``)."""
+        return int(self.nbr.nbytes + self.w.nbytes)
+
+    @staticmethod
+    def from_edges(n: int, a, b, weight, *, D: int,
+                   colors) -> "NeighborGraph":
+        """Build from an undirected edge list: edge ``e`` joins sites
+        ``a[e] != b[e]`` with match weight ``weight[e] != 0``, each pair at
+        most once.  Vectorised, O(E log E); no (n, n) array.  Raises
+        ``ValueError`` for a self loop, a repeated pair, a zero weight or
+        a colouring that is not proper over the edges."""
+        with get_recorder().span("repro.graph.build", n=n):
+            a = np.asarray(a, np.int64)
+            b = np.asarray(b, np.int64)
+            wt = np.asarray(weight, np.float32)
+            colors = np.asarray(colors, np.int32)
+            if colors.shape != (n,):
+                raise ValueError(f"colors must have shape ({n},), got "
+                                 f"{colors.shape}")
+            if a.size and (min(a.min(), b.min()) < 0
+                           or max(a.max(), b.max()) >= n):
+                raise ValueError(f"edge endpoints out of range [0, {n})")
+            if np.any(a == b):
+                raise ValueError("self loops are not pairwise factors")
+            if np.any(wt == 0.0):
+                raise ValueError("an edge has weight 0 (padding's mark)")
+            if colors.size and colors.min() < 0:
+                raise ValueError("colors must be >= 0")
+            if np.any(np.bincount(colors) == 0):
+                raise ValueError("colors leaves a colour class empty")
+            if np.any(colors[a] == colors[b]):
+                raise ValueError("colors is not a proper coloring: an edge "
+                                 "joins two sites of one colour")
+            src = np.concatenate([a, b])
+            dst = np.concatenate([b, a])
+            ww = np.concatenate([wt, wt])
+            order = np.argsort(src * n + dst, kind="stable")
+            src, dst, ww = src[order], dst[order], ww[order]
+            if np.any((src[1:] == src[:-1]) & (dst[1:] == dst[:-1])):
+                raise ValueError("a pair of sites is joined twice")
+            deg = np.bincount(src, minlength=n)
+            delta = int(deg.max()) if n else 0
+            slot = np.arange(src.size) - (np.cumsum(deg) - deg)[src]
+            nbr = np.repeat(np.arange(n, dtype=np.int32)[:, None],
+                            delta, axis=1)
+            nbr[src, slot] = dst
+            w = np.zeros((n, delta), np.float32)
+            w[src, slot] = ww
+            g = NeighborGraph(nbr=jnp.asarray(nbr), w=jnp.asarray(w),
+                              colors=colors, D=D, delta=delta)
+        get_recorder().gauge("graph_table_bytes", g.table_bytes)
+        return g
+
+    @staticmethod
+    def from_match_graph(g: MatchGraph, colors) -> "NeighborGraph":
+        """The neighbour lists of a (small) dense graph: every
+        ``W_ij != 0`` is an edge."""
+        W = np.asarray(g.W)
+        a, b = np.nonzero(np.triu(W, 1))
+        return NeighborGraph.from_edges(W.shape[0], a, b, W[a, b], D=g.D,
+                                        colors=colors)
+
+
+def make_lattice_graph(grid: int, beta: float, D: int = 2,
+                       periodic: bool = True) -> NeighborGraph:
+    """Nearest-neighbour lattice as neighbour lists: ``grid x grid`` sites
+    numbered row-major, an edge to each of the 4 lattice neighbours (wrapped
+    round when ``periodic``), match weight ``2 beta`` on every edge (the
+    Ising convention of :func:`make_lattice_ising`: ``2 beta d(x_i, x_j)``
+    is ``beta s_i s_j`` up to a constant at D = 2), checkerboard colouring.
+
+    A periodic lattice needs an even side >= 4 (an odd one has no
+    checkerboard round the wrap; a side of 2 would join a pair twice)."""
+    if periodic and (grid < 4 or grid % 2):
+        raise ValueError(f"a periodic lattice needs an even side >= 4, "
+                         f"got {grid}")
+    n = grid * grid
+    r, c = np.divmod(np.arange(n, dtype=np.int64), grid)
+    # each edge once: to the right and downward neighbour
+    if periodic:
+        a = np.concatenate([r * grid + c, r * grid + c])
+        b = np.concatenate([r * grid + (c + 1) % grid,
+                            ((r + 1) % grid) * grid + c])
+    else:
+        right, down = c + 1 < grid, r + 1 < grid
+        a = np.concatenate([(r * grid + c)[right], (r * grid + c)[down]])
+        b = np.concatenate([(r * grid + c + 1)[right],
+                            ((r + 1) * grid + c)[down]])
+    return NeighborGraph.from_edges(
+        n, a, b, np.full(a.shape, 2.0 * beta, np.float32), D=D,
+        colors=lattice_colors(grid))
 
 
 # ---------------------------------------------------------------------------

@@ -43,7 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .factor_graph import MatchGraph, alias_draw, build_alias_table
+from .factor_graph import (MatchGraph, NeighborGraph, alias_draw,
+                           build_alias_table)
 from .estimators import (draw_global_minibatch, draw_local_minibatch,
                          min_gibbs_estimate)
 from ..kernels import ops as kernel_ops
@@ -982,6 +983,97 @@ def _build_chromatic_gibbs_sweep(graph: MatchGraph, colors, *,
         return new, SweepStats(site_prop=hits, site_acc=hits)
 
     return sweep
+
+
+# ---------------------------------------------------------------------------
+# Parallel chromatic sweep over neighbour lists (NeighborGraph)
+# ---------------------------------------------------------------------------
+
+def _build_neighbor_chromatic_sweep(graph: NeighborGraph, *,
+                                    collect_stats: bool = False):
+    """One full chromatic Gibbs sweep per call over a :class:`NeighborGraph`:
+    each colour class updated at once, every site of it in every chain,
+    from the state the previous class left.  One jitted program a call
+    (``jit_gibbs_chromatic_sweep``), dispatched under ``repro.sweep.kernel``.
+
+    Draw contract, per chain, whose key is ``k`` (a (2,) uint32 of the
+    state's (C, 2) keys), with ``K`` colour classes:
+
+    * ``ks = jax.random.split(k, K + 1)``; ``ks[K]`` is the chain's next
+      key, ``ks[q]`` feeds class ``q``;
+    * class ``q`` (its ``n_q`` sites in ascending index order) draws
+      ``jax.random.gumbel(ks[q], (n_q, D))`` float32; row ``r`` is the
+      noise of the class's ``r``-th site;
+    * classes run in order ``q = 0 .. K-1``.  Site ``i`` of class ``q`` takes
+      ``argmax_u (e_u + g_u)`` (the first on ties), where ``e_u`` is the
+      float32 sum over the slots ``k = 0 .. delta-1`` of ``nbr[i]``, added
+      left to right from the first, of ``w[i, k]`` where
+      ``x[nbr[i, k]] == u`` and 0 elsewhere; ``x`` is the chain's state
+      after the classes before ``q`` (same-class sites share no edge);
+    * ``accepts`` is left as it is (every update is an exact Gibbs draw).
+
+    ``evidence=`` (``(ev_mask (n,) float32, ev_vals (n,) int32)``)
+    re-clamps the observed sites after every class, as the dense chromatic
+    sweep does; an all-zero mask is the unconditional sweep bit for bit.
+    ``updates_per_call`` is n.
+    """
+    nbr, w = np.asarray(graph.nbr), np.asarray(graph.w)
+    rank = np.zeros(graph.n, np.int32)       # a site's row in its class
+    classes = []
+    for q in range(graph.n_colors):
+        sites = np.flatnonzero(graph.colors == q)
+        rank[sites] = np.arange(sites.size)
+        classes.append((jnp.asarray(nbr[sites].T), jnp.asarray(w[sites].T)))
+    tables = (jnp.asarray(graph.colors), jnp.asarray(rank), tuple(classes))
+    program = _chromatic_program(graph.D)
+
+    def sweep(state: ChainState, evidence=None):
+        with get_recorder().span("repro.sweep.kernel"):
+            x, knew = program(state.x, state.key, tables, evidence)
+        new = state._replace(x=x, key=knew)
+        if not collect_stats:
+            return new
+        hits = jnp.full((graph.n,), jnp.float32(state.x.shape[0]))
+        return new, SweepStats(site_prop=hits, site_acc=hits)
+
+    return sweep
+
+
+def _chromatic_program(D: int):
+    """The jitted sweep of :func:`_build_neighbor_chromatic_sweep`:
+    ``(x (C, n) int32, key (C, 2) uint32, tables, evidence) -> (x, key)``
+    with ``tables = (colors (n,), rank (n,), classes)``: ``rank[i]`` is
+    site ``i``'s row in its class, and ``classes`` holds one ``(nbr, w)``
+    pair of (delta, n_q) tables a colour class, slot-major, in class
+    order.  Each class is written back by a select over the whole state
+    (the class's rows gathered to their sites), not a scatter: on a TPU
+    v5e at 64 chains x 2^20 sites a column scatter into the (C, n) state
+    took 92 ms a class, and the whole sweep 254 ms with scatters against
+    86 ms with this select."""
+
+    def gibbs_chromatic_sweep(x, key, tables, evidence):
+        colors, rank, classes = tables
+        K = len(classes)
+        ks = jax.vmap(lambda k: jax.random.split(k, K + 1))(key)
+        for q, (nb, wq) in enumerate(classes):
+            g = jax.vmap(lambda k: jax.random.gumbel(
+                k, (nb.shape[1], D)))(ks[:, q])             # (C, n_q, D)
+            xn = x[:, nb]                                   # (C, delta, n_q)
+            scores = []
+            for u in range(D):
+                e = jnp.where(xn[:, 0] == u, wq[0], 0.0)
+                for k in range(1, nb.shape[0]):
+                    e = e + jnp.where(xn[:, k] == u, wq[k], 0.0)
+                scores.append(e + g[..., u])
+            v = jnp.argmax(jnp.stack(scores, -1), axis=-1).astype(x.dtype)
+            x = jnp.where(colors[None, :] == q,
+                          jnp.take(v, rank, axis=1, mode="clip"), x)
+            if evidence is not None:
+                x = jnp.where(evidence[0][None, :] > 0.0,
+                              evidence[1][None, :].astype(x.dtype), x)
+        return x, ks[:, K]
+
+    return jax.jit(gibbs_chromatic_sweep)
 
 
 # ---------------------------------------------------------------------------

@@ -185,7 +185,7 @@ class Recorder(NullRecorder):
         if chains:
             self.metrics.gauge("engine_chains", chains,
                                help="resident chains", **labels)
-        n = int(eng.graph.W.shape[0])
+        n = int(eng.graph.n)
         cost = _sweep_cost(eng, chains or 1, n)
         self.metrics.gauge("sweep_flops_per_call", cost["flops_per_call"],
                            help="analytic flops per sweep call", **labels)

@@ -154,3 +154,27 @@ def test_pallas_engine_beyond_vmem_raises_clear_error(monkeypatch):
     fits = engine.make("min-gibbs", g, sweep=S, backend="auto", lam=147.0)
     assert fits.describe()["backend"] == "pallas"
     assert fits.describe()["interpret"] is False
+
+
+def test_chromatic_sweep_compiles_for_v5e_at_the_cell_size(topo):
+    """The neighbour-list chromatic sweep of ``lattice1024-gibbs-chromatic``
+    (64 chains x 2^20 sites, D = 2, Delta = 4, two colour classes of 2^19
+    sites) lowers as ``jit_gibbs_chromatic_sweep`` and compiles for one
+    v5e chip within its 16 GB."""
+    from jax.sharding import SingleDeviceSharding
+    from repro.core import samplers
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    C, n = 64, 2 ** 20
+    half = n // 2
+    tables = (sds((n,), jnp.int32), sds((n,), jnp.int32),
+              tuple((sds((4, half), jnp.int32), sds((4, half), jnp.float32))
+                    for _ in range(2)))
+    lowered = samplers._chromatic_program(2).lower(
+        sds((C, n), jnp.int32), sds((C, 2), jnp.uint32), tables, None)
+    assert "jit_gibbs_chromatic_sweep" in lowered.as_text()
+    mem = lowered.compile().memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert 0 < used < 16e9, used
