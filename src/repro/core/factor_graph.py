@@ -30,7 +30,7 @@ alias-table *construction* happens once in numpy (Vose's algorithm).
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -332,6 +332,11 @@ class NeighborGraph:
              0..k-1, each used.
     D      : domain size of every variable.
     delta  : max degree Delta (the row width).
+    grid   : ``(H, W)`` where the sites are an ``H x W`` grid numbered
+             row-major and every edge joins lattice neighbours (wrapped
+             round or not), else None.  A claim about the input that the
+             chromatic sweep checks against ``nbr`` before it reads
+             neighbours as shifts of the grid in place of gathers.
     """
 
     nbr: jax.Array
@@ -339,6 +344,7 @@ class NeighborGraph:
     colors: np.ndarray
     D: int
     delta: int
+    grid: Optional[Tuple[int, int]] = None
 
     @property
     def n(self) -> int:
@@ -354,11 +360,12 @@ class NeighborGraph:
         return int(self.nbr.nbytes + self.w.nbytes)
 
     @staticmethod
-    def from_edges(n: int, a, b, weight, *, D: int,
-                   colors) -> "NeighborGraph":
+    def from_edges(n: int, a, b, weight, *, D: int, colors,
+                   grid: Optional[Tuple[int, int]] = None) -> "NeighborGraph":
         """Build from an undirected edge list: edge ``e`` joins sites
         ``a[e] != b[e]`` with match weight ``weight[e] != 0``, each pair at
-        most once.  Vectorised, O(E log E); no (n, n) array.  Raises
+        most once; ``grid`` is passed through to the graph.  Vectorised,
+        O(E log E); no (n, n) array.  Raises
         ``ValueError`` for a self loop, a repeated pair, a zero weight or
         a colouring that is not proper over the edges."""
         with get_recorder().span("repro.graph.build", n=n):
@@ -399,7 +406,8 @@ class NeighborGraph:
             w = np.zeros((n, delta), np.float32)
             w[src, slot] = ww
             g = NeighborGraph(nbr=jnp.asarray(nbr), w=jnp.asarray(w),
-                              colors=colors, D=D, delta=delta)
+                              colors=colors, D=D, delta=delta,
+                              grid=None if grid is None else tuple(grid))
         get_recorder().gauge("graph_table_bytes", g.table_bytes)
         return g
 
@@ -440,7 +448,7 @@ def make_lattice_graph(grid: int, beta: float, D: int = 2,
                             ((r + 1) * grid + c)[down]])
     return NeighborGraph.from_edges(
         n, a, b, np.full(a.shape, 2.0 * beta, np.float32), D=D,
-        colors=lattice_colors(grid))
+        colors=lattice_colors(grid), grid=(grid, grid))
 
 
 # ---------------------------------------------------------------------------

@@ -6,18 +6,23 @@ Gibbs sweep (``engine.make("gibbs", g, schedule="chromatic")``):
     span and table gauge recorded;
   * the sweep — equal bit for bit to the plain reference of its draw
     contract (``tests/_lattice_ref.py``) on seeded random per-edge weights,
-    D = 2 and 3, with and without evidence, over several calls; exact
+    D = 2 and 3, with and without evidence, over several calls, both on
+    gathers (a graph with no grid) and on shifts of a checked grid, where
+    it also equals the gather program; a grid that its tables belie runs
+    on gathers, as the ``chromatic_stencil`` gauge records; exact
     marginals of a 4x4 lattice by enumeration;
   * the contract around it — ``ChainPool`` serves it with one compiled
     chunk, and ``make`` refuses what the layout does not run.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import _lattice_ref as ref
-from repro.core import engine
+from repro.core import engine, samplers
 from repro.core.factor_graph import (NeighborGraph, lattice_colors,
                                      make_lattice_graph, make_lattice_ising)
 from repro.diagnostics import FreshnessPolicy
@@ -41,6 +46,7 @@ def test_neighbor_lists_match_lattice_edges(periodic):
                               for row in want]
     nbr, w = np.asarray(g.nbr), np.asarray(g.w)
     assert nbr.dtype == np.int32 and w.dtype == np.float32
+    assert g.grid == (8, 8)
     if periodic:                      # every site has its 4 neighbours
         assert g.delta == 4 and np.all(w == np.float32(0.8))
     else:                             # the dense build's edges and weights
@@ -76,13 +82,20 @@ def test_build_records_span_and_table_bytes():
         == 256 * 4 * 8
 
 
-def _random_weight_graph(grid, periodic, D, seed):
+def _random_weight_graph(grid, periodic, D, seed, *, record_grid=False,
+                         rewire=False):
+    """The lattice with seeded random per-edge weights; ``record_grid``
+    records its ``grid`` on the graph, and ``rewire`` moves edge (0, 1) to
+    (0, 3), which keeps the colouring proper but is no lattice edge."""
     edges = ref.lattice_edges(grid, periodic)
+    if rewire:
+        edges = sorted(set(edges) - {(0, 1)} | {(0, 3)})
     w = np.random.default_rng(seed).uniform(0.1, 1.5, len(edges)).astype(
         np.float32)
     a, b = np.array(edges).T
     g = NeighborGraph.from_edges(grid * grid, a, b, w, D=D,
-                                 colors=lattice_colors(grid))
+                                 colors=lattice_colors(grid),
+                                 grid=(grid, grid) if record_grid else None)
     return g, edges, w
 
 
@@ -108,6 +121,58 @@ def test_sweep_equals_reference_bit_for_bit(D, periodic, with_evidence):
         assert np.array_equal(np.asarray(st.x), x)
         assert np.array_equal(np.asarray(st.key), keys)
     assert np.all(np.asarray(st.accepts) == 0)
+
+
+def _evidence(n, D):
+    mask = np.zeros(n, np.float32)
+    vals = np.zeros(n, np.int32)
+    mask[[0, 9, 27, 62]], vals[[0, 9, 27, 62]] = 1.0, [1, D - 1, 0, 1]
+    return jnp.asarray(mask), jnp.asarray(vals)
+
+
+@pytest.mark.parametrize("D, periodic, with_evidence", [
+    (2, True, False), (2, False, True), (3, True, True), (3, False, False)])
+def test_stencil_sweep_equals_reference_and_gathers(D, periodic,
+                                                    with_evidence):
+    g, edges, w = _random_weight_graph(8, periodic, D, seed=10 + D,
+                                      record_grid=True)
+    assert samplers._grid_stencil_tables(g) is not None
+    shifts = samplers._build_neighbor_chromatic_sweep(g)
+    gathers = samplers._build_neighbor_chromatic_sweep(
+        dataclasses.replace(g, grid=None))
+    eng = engine.make("gibbs", g, schedule="chromatic", backend="jnp")
+    ev = _evidence(g.n, D) if with_evidence else None
+    st = eng.init(jax.random.PRNGKey(7), 3, start="random")
+    other = st
+    x, keys = np.asarray(st.x), np.asarray(st.key)
+    for _ in range(4):
+        st, other = shifts(st, evidence=ev), gathers(other, evidence=ev)
+        x, keys = ref.sweep(x, keys, g.n, D, edges, w, g.colors, ev)
+        assert np.array_equal(np.asarray(st.x), x)
+        assert np.array_equal(np.asarray(st.key), keys)
+        assert np.array_equal(np.asarray(other.x), x)
+        assert np.array_equal(np.asarray(other.key), keys)
+
+
+@pytest.mark.parametrize("rewire, grid_kept, stencil", [
+    (False, True, 1.0), (True, True, 0.0), (False, False, 0.0)],
+    ids=["grid", "rewired-edge", "no-grid"])
+def test_stencil_gauge_follows_the_checked_tables(rewire, grid_kept,
+                                                  stencil):
+    g, edges, w = _random_weight_graph(8, True, 2, seed=3,
+                                      record_grid=grid_kept, rewire=rewire)
+    rec = Recorder()
+    with using(rec):
+        sweep = samplers._build_neighbor_chromatic_sweep(g)
+    assert rec.metrics.value("chromatic_stencil") == stencil
+    assert (samplers._grid_stencil_tables(g) is not None) == bool(stencil)
+    eng = engine.make("gibbs", g, schedule="chromatic", backend="jnp")
+    st = eng.init(jax.random.PRNGKey(2), 2, start="random")
+    x, keys = ref.sweep(np.asarray(st.x), np.asarray(st.key), g.n, 2,
+                        edges, w, g.colors)
+    st = sweep(st)
+    assert np.array_equal(np.asarray(st.x), x)
+    assert np.array_equal(np.asarray(st.key), keys)
 
 
 def test_marginals_match_enumeration():

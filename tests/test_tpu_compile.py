@@ -178,3 +178,25 @@ def test_chromatic_sweep_compiles_for_v5e_at_the_cell_size(topo):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < 16e9, used
+
+
+def test_stencil_chromatic_sweep_compiles_for_v5e_at_the_cell_size(topo):
+    """The same sweep on shifts of the checked 1024 x 1024 grid (the path
+    ``lattice1024-gibbs-chromatic`` takes) lowers as
+    ``jit_gibbs_chromatic_sweep`` and compiles for one v5e chip within its
+    16 GB."""
+    from jax.sharding import SingleDeviceSharding
+    from repro.core import samplers
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    C, H, W = 64, 1024, 1024
+    tables = tuple((sds((4, H * W // 2), jnp.int32),
+                    sds((4, H * W // 2), jnp.float32)) for _ in range(2))
+    lowered = samplers._stencil_program(2, (H, W)).lower(
+        sds((C, H * W), jnp.int32), sds((C, 2), jnp.uint32), tables, None)
+    assert "jit_gibbs_chromatic_sweep" in lowered.as_text()
+    mem = lowered.compile().memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert 0 < used < 16e9, used
